@@ -41,7 +41,7 @@ from repro.optim import adam, apply_updates
 
 @functools.lru_cache(maxsize=None)
 def _cached_fwd(space, noise_dim: int, use_fused: Optional[bool] = None,
-                chained: bool = None):
+                chained: bool = None, mesh=None):
     """Jitted MLP inference, cached on (space, noise_dim, use_fused) like
     the explorer's G forward: retrains / new LargeMLP instances never
     recompile.
@@ -54,6 +54,8 @@ def _cached_fwd(space, noise_dim: int, use_fused: Optional[bool] = None,
     parity contract, identical to the Explorer's).  On the fused route
     (``chained`` None = dispatch auto) the draws flatten into one row
     batch through the layer-chained megakernel, mirroring the Explorer.
+    ``mesh``: the task mesh ``fwd_mean``'s inputs are sharded over (None =
+    one device); the kernel route runs per shard on it.
     """
     from repro.kernels import dispatch as D
     if chained is None:
@@ -80,7 +82,8 @@ def _cached_fwd(space, noise_dim: int, use_fused: Optional[bool] = None,
                 net_enc, obj_enc, keys, n_samples, noise_fn)
             x = jnp.concatenate([net_r, obj_r, noise_r], axis=-1)
             probs = _probs_logits(
-                L.mlp_apply_chained(params, x, use_fused=use_fused))
+                L.mlp_apply_chained(params, x, use_fused=use_fused,
+                                    mesh=mesh))
             return jnp.mean(probs.reshape(t, n_samples, -1), axis=1)
 
         def one_task(net, obj, key):
@@ -112,16 +115,15 @@ class LargeMLP:
     def __post_init__(self):
         self.ds: Optional[Dataset] = None
         self.params = None
-        self._fwd, self._fwd_mean = _cached_fwd(self.model.space,
-                                                self.noise_dim,
-                                                self.use_fused)
+        self._fwd = _cached_fwd(self.model.space, self.noise_dim,
+                                self.use_fused)[0]
 
     def set_use_fused(self, use_fused: Optional[bool]) -> "LargeMLP":
         """Flip the fused-MLP dispatch (serving-layer override hook);
         refreshes the cached jitted forwards for the new route."""
         self.use_fused = use_fused
-        self._fwd, self._fwd_mean = _cached_fwd(self.model.space,
-                                                self.noise_dim, use_fused)
+        self._fwd = _cached_fwd(self.model.space, self.noise_dim,
+                                use_fused)[0]
         return self
 
     def n_params(self) -> int:
@@ -189,7 +191,9 @@ class LargeMLP:
         keys = task_keys(seed, net_enc.shape[0])
         # task-sharded over the active mesh (no-op without one): put_sharded
         # is a drop-in for jnp.asarray, see repro.core.shard
-        return self._fwd_mean(self.params, shard.put_sharded(net_enc),
+        fwd_mean = _cached_fwd(self.model.space, self.noise_dim,
+                               self.use_fused, mesh=shard.get_task_mesh())[1]
+        return fwd_mean(self.params, shard.put_sharded(net_enc),
                               shard.put_sharded(obj_enc),
                               shard.put_sharded(keys),
                               n_samples=self.explorer_cfg.noise_samples)

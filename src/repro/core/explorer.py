@@ -327,10 +327,12 @@ def flatten_task_draws(net_enc, obj_enc, keys, n_samples: int, noise_fn):
 
 @functools.lru_cache(maxsize=None)
 def _cached_fwd(space: ConfigSpace, gan_cfg: G.GANConfig,
-                chained: bool = None):
-    """Module-level jitted G inference, cached on (space, gan_cfg): a fresh
-    Explorer (e.g. per retrain / hot-swap) reuses the compiled forward
-    instead of recompiling from scratch.
+                chained: bool = None, mesh=None):
+    """Module-level jitted G inference, cached on (space, gan_cfg, mesh): a
+    fresh Explorer (e.g. per retrain / hot-swap) reuses the compiled
+    forward instead of recompiling from scratch.  ``mesh`` is the task
+    mesh the forward's inputs are sharded over (None = one device); the
+    kernel route runs per shard on it (kernels/dispatch.py).
 
     Per-task noise streams: task t averages n_samples draws from
     fold_in(keys[t], s) — the same streams whether tasks run one at a time
@@ -357,15 +359,15 @@ def _cached_fwd(space: ConfigSpace, gan_cfg: G.GANConfig,
                 net_enc, obj_enc, keys, n_samples, noise_fn)
             probs = G.generator_apply(
                 g_params, space, net_r, obj_r, noise_r,
-                use_fused=gan_cfg.use_fused, chained=True)
+                use_fused=gan_cfg.use_fused, chained=True, mesh=mesh)
             return jnp.mean(probs.reshape(t, n_samples, -1), axis=1)
 
         def one_task(net, obj, key):
             def one(s):
                 noise = G.sample_noise(jax.random.fold_in(key, s), 1, gan_cfg)
                 return G.generator_apply(g_params, space, net[None], obj[None],
-                                         noise,
-                                         use_fused=gan_cfg.use_fused)[0]
+                                         noise, use_fused=gan_cfg.use_fused,
+                                         mesh=mesh)[0]
             return jnp.mean(jax.vmap(one)(jnp.arange(n_samples)), axis=0)
 
         return jax.vmap(one_task)(net_enc, obj_enc, keys)
@@ -383,8 +385,11 @@ class Explorer:
     gan_cfg: G.GANConfig
     cfg: ExplorerConfig = dataclasses.field(default_factory=ExplorerConfig)
 
-    def __post_init__(self):
-        self._fwd = _cached_fwd(self.model.space, self.gan_cfg)
+    @property
+    def _fwd(self):
+        """The jitted G forward for the active task mesh (cached)."""
+        return _cached_fwd(self.model.space, self.gan_cfg,
+                           mesh=shard.get_task_mesh())
 
     def generator_probs_device(self, net_idx: np.ndarray, lat_obj, pow_obj,
                                seed: int = 0) -> jnp.ndarray:

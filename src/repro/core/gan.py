@@ -73,21 +73,21 @@ def init_discriminator(rng, cfg: GANConfig, space: ConfigSpace):
 
 def generator_apply(params, space: ConfigSpace, net_enc, obj_enc, noise,
                     use_fused: Optional[bool] = None, chained: bool = False,
-                    interpret: bool = False):
+                    mesh=None):
     """Returns (B, onehot_width) per-group softmax probabilities.
 
     ``use_fused`` follows the dispatch rule (None = backend auto);
     ``chained=True`` takes the layer-chained megakernel on the fused
     route — the inference fast path (training wants the per-layer
-    backward, so the train step leaves it False).
+    backward, so the train step leaves it False).  ``mesh``: the devices
+    the calling program spans (kernels/dispatch.py).
     """
     x = jnp.concatenate([net_enc, obj_enc, noise], axis=-1)
     if chained:
         logits = L.mlp_apply_chained(params, x, use_fused=use_fused,
-                                     interpret=interpret)
+                                     mesh=mesh)
     else:
-        logits = L.mlp_apply(params, x, use_fused=use_fused,
-                             interpret=interpret)
+        logits = L.mlp_apply(params, x, use_fused=use_fused, mesh=mesh)
     gidx, mask, flat2pad = _padded_layout(space)
     padded = jnp.where(mask, logits[..., gidx], -jnp.inf)
     probs = jax.nn.softmax(padded, axis=-1)      # pad -inf -> exactly 0
@@ -95,11 +95,10 @@ def generator_apply(params, space: ConfigSpace, net_enc, obj_enc, noise,
 
 
 def discriminator_apply(params, net_enc, cfg_onehot, obj_enc,
-                        use_fused: Optional[bool] = None,
-                        interpret: bool = False):
+                        use_fused: Optional[bool] = None, mesh=None):
     """Returns (B, 2) satisfaction logits ([False, True] classes)."""
     x = jnp.concatenate([net_enc, cfg_onehot, obj_enc], axis=-1)
-    return L.mlp_apply(params, x, use_fused=use_fused, interpret=interpret)
+    return L.mlp_apply(params, x, use_fused=use_fused, mesh=mesh)
 
 
 def replicate_params(params, mesh=None):

@@ -176,7 +176,7 @@ def _make_step_body(model: DesignModel, cfg: G.GANConfig,
     def losses_g(g_params, d_params, batch, noise):
         probs = G.generator_apply(g_params, space, batch["net_enc"],
                                   batch["obj_enc"], noise,
-                                  use_fused=cfg.use_fused)
+                                  use_fused=cfg.use_fused, mesh=mesh)
         # --- external design model on the hard-decoded config (lines 7-8)
         cfg_idx = G.decode_hard(space, probs)
         lat_g, pow_g = oracle(cfg_idx, batch["net_idx"])
@@ -187,7 +187,7 @@ def _make_step_body(model: DesignModel, cfg: G.GANConfig,
         # flow *through* D into G's probs — that is the critic signal.
         sat_logits = G.discriminator_apply(d_params, batch["net_enc"], probs,
                                            batch["obj_enc"],
-                                           use_fused=cfg.use_fused)
+                                           use_fused=cfg.use_fused, mesh=mesh)
         loss_critic = jnp.mean(G.satisfaction_ce(sat_logits, jnp.ones_like(sat_actual)))
         ce_cfg = G.grouped_cross_entropy(space, batch["cfg_onehot"], probs)
         loss_config = jnp.mean((1.0 - sat_actual) * ce_cfg)       # masked (line 11/14)
@@ -201,7 +201,7 @@ def _make_step_body(model: DesignModel, cfg: G.GANConfig,
         probs = jax.lax.stop_gradient(probs)
         sat_logits = G.discriminator_apply(d_params, batch["net_enc"], probs,
                                            batch["obj_enc"],
-                                           use_fused=cfg.use_fused)
+                                           use_fused=cfg.use_fused, mesh=mesh)
         loss_dis = jnp.mean(G.satisfaction_ce(sat_logits, sat_actual))  # lines 12/15
         d_acc = jnp.mean(
             (jnp.argmax(sat_logits, -1).astype(jnp.float32) == sat_actual).astype(jnp.float32)
@@ -348,14 +348,20 @@ def train_gan(
     ``mesh=None`` picks up the active task mesh (``shard.set_task_mesh``);
     with one, each epoch runs data-parallel over the mesh's batch axes —
     replicated donated carry, per-device row gathers, gradients
-    all-reduced over ('pod', 'data') — and falls back to the unsharded
-    path when the batch size does not divide the shard count.  Losses are
+    all-reduced over ('pod', 'data') — and falls back, with a
+    RuntimeWarning, to the unsharded one-device path when the batch size
+    does not divide the shard count.  Losses are
     batch means either way, so sharded training matches single-device up
     to float reduction order (pinned by tests/test_shard.py).
     """
     mesh = shard.get_task_mesh() if mesh is None else mesh
-    if shard.n_task_shards(mesh) <= 1 or min(cfg.batch_size, ds.n) % \
-            shard.n_task_shards(mesh) != 0:
+    k = shard.n_task_shards(mesh)
+    if k <= 1:
+        mesh = None
+    elif min(cfg.batch_size, ds.n) % k:
+        warnings.warn(f"train_gan: batch {min(cfg.batch_size, ds.n)} does "
+                      f"not divide the {k} task shards; training unsharded "
+                      f"on one device", RuntimeWarning, stacklevel=2)
         mesh = None
     g_optim, d_optim, epoch = _cached_epoch_fn(model, cfg, use_jax_oracle,
                                                mesh)
@@ -378,8 +384,9 @@ def train_gan(
     if mesh is not None:
         data = shard.replicate(data, mesh)
 
-    carry = shard.replicate(
-        (g_params, d_params, g_opt, d_opt, rng), mesh)
+    carry = (g_params, d_params, g_opt, d_opt, rng)
+    if mesh is not None:
+        carry = shard.replicate(carry, mesh)
     history: List[Dict[str, float]] = []
     t0 = time.time()
     for it in range(iters):

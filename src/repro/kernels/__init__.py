@@ -7,7 +7,7 @@
 
 Each kernel ships with ``ref.py`` (pure-jnp oracle) and is validated in
 interpret mode on CPU; ``dispatch.py`` is the single backend-aware
-routing point (TPU -> Pallas, CPU/GPU -> jnp reference, ``interpret``
-and ``force_interpret()`` for tests); ``ops.py`` keeps thin jit wrappers.
+routing point (TPU -> Pallas, CPU/GPU -> jnp reference, and the
+``force_interpret()`` hook for tests); ``ops.py`` keeps thin jit wrappers.
 """
 from repro.kernels import dispatch, ops  # noqa: F401

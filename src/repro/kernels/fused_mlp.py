@@ -20,8 +20,13 @@ Tiling (shared by forward and backward): grid (rows/bm, cols/bn, red/bk)
 with the reduction axis innermost (sequential), accumulating into a VMEM
 f32 scratch tile; on the last reduction step the epilogue (bias+ReLU, or
 the output cast) runs and the tile is written once.  VMEM working set =
-bm*bk + bk*bn + bm*bn (+ bn bias) floats; the default (256, 512, 512)
-tiles use ~1.6 MB — far below the ~16 MB/core budget and MXU-aligned.
+2 * (bm*bk + bk*bn + bm*bn + bn) floats for the double-buffered blocks
+plus the bm*bn accumulator: 4.5 MiB for the default (256, 512, 512) f32
+tiles, well inside the default scoped VMEM limit, and MXU-aligned.
+Biases travel as
+(1, N) rows with (1, bn) blocks: TPU blocks need their last two dims
+divisible by (8, 128) or equal to the array's, and 1-D blocks have no
+layout Mosaic and XLA agree on.
 Operands whose dims do not divide the block are zero-padded up to the
 block multiple (and outputs sliced back), so a prime/odd dim can never
 force a whole-dim block past the VMEM budget.
@@ -60,9 +65,12 @@ def _pad2(a: jnp.ndarray, rows: int, cols: int) -> jnp.ndarray:
     return jnp.pad(a, ((0, pr), (0, pc))) if pr or pc else a
 
 
-def _pad1(a: jnp.ndarray, n: int) -> jnp.ndarray:
-    p = n - a.shape[0]
-    return jnp.pad(a, (0, p)) if p else a
+def _bias_row(b: jnp.ndarray, n: int) -> jnp.ndarray:
+    """(N,) bias -> zero-padded (1, n) row.  Biases travel as 2-D rows: a
+    1-D block has no legal TPU tiling (Mosaic's 1-D layout differs from
+    XLA's), while a (1, bn) block is legal whenever bn is a multiple of
+    128 or the whole padded width."""
+    return _pad2(b.reshape(1, -1), 1, n)
 
 
 # ---------------------------------------------------------------------------
@@ -83,7 +91,7 @@ def _fused_dense_kernel(x_ref, w_ref, b_ref, o_ref, acc_ref, *, n_k: int, relu: 
 
     @pl.when(k_step == n_k - 1)
     def _epilogue():
-        y = acc_ref[...] + b_ref[...].astype(jnp.float32)[None, :]
+        y = acc_ref[...] + b_ref[...].astype(jnp.float32)
         if relu:
             y = jnp.maximum(y, 0.0)
         o_ref[...] = y.astype(o_ref.dtype)
@@ -95,7 +103,7 @@ def _forward(x, w, b, *, relu: bool, bm: int, bk: int, bn: int, interpret: bool)
     assert k == k2 and b.shape == (n,)
     bm, bk, bn = _pick(bm, m), _pick(bk, k), _pick(bn, n)
     mp, kp, np_ = _round_up(m, bm), _round_up(k, bk), _round_up(n, bn)
-    xp, wp, bp = _pad2(x, mp, kp), _pad2(w, kp, np_), _pad1(b, np_)
+    xp, wp, bp = _pad2(x, mp, kp), _pad2(w, kp, np_), _bias_row(b, np_)
     n_k = kp // bk
 
     grid = (mp // bm, np_ // bn, n_k)
@@ -105,7 +113,7 @@ def _forward(x, w, b, *, relu: bool, bm: int, bk: int, bn: int, interpret: bool)
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
             pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)),
-            pl.BlockSpec((bn,), lambda i, j, kk: (j,)),
+            pl.BlockSpec((1, bn), lambda i, j, kk: (0, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), x.dtype),
@@ -284,7 +292,7 @@ def _mlp_kernel(x_ref, w_ref, b_ref, o_ref, h0_ref, h1_ref, *, n_layers: int):
 
     y = jnp.dot(h_in, w_ref[0].astype(jnp.float32),
                 preferred_element_type=jnp.float32)
-    y = y + b_ref[...].astype(jnp.float32)
+    y = y + b_ref[0].astype(jnp.float32)
     y = jnp.where(l == n_layers - 1, y, jnp.maximum(y, 0.0))
 
     col = pl.multiple_of(j * bn, bn)
@@ -318,7 +326,7 @@ def _mlp_forward(x, ws, bs, *, bm: int, bn: int, interpret: bool):
     # every layer padded onto the (h, h) square: zero rows/cols keep the
     # chain exact (relu(0·x + 0) = 0 rides along and is sliced off at the end)
     w_stack = jnp.stack([_pad2(w, h, h) for w in ws])           # (L, h, h)
-    b_stack = jnp.stack([_pad1(b, h) for b in bs])              # (L, h)
+    b_stack = jnp.stack([_bias_row(b, h) for b in bs])          # (L, 1, h)
     xp = _pad2(x, mp, h)
 
     grid = (mp // bm, n_layers, h // bn)
@@ -328,7 +336,7 @@ def _mlp_forward(x, ws, bs, *, bm: int, bn: int, interpret: bool):
         in_specs=[
             pl.BlockSpec((bm, h), lambda i, l, j: (i, 0)),
             pl.BlockSpec((1, h, bn), lambda i, l, j: (l, 0, j)),
-            pl.BlockSpec((1, bn), lambda i, l, j: (l, j)),
+            pl.BlockSpec((1, 1, bn), lambda i, l, j: (l, 0, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, l, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, h), x.dtype),
@@ -391,8 +399,10 @@ def fused_mlp(
     """Whole-MLP forward (hidden ReLU, linear head) as ONE pallas_call:
     activations stay in VMEM across the layer grid axis (two ping-pong
     scratch buffers) instead of an HBM round-trip per layer.  VMEM working
-    set: x block (bm·h) + weight slab (h·bn) + 2 activation buffers (bm·h)
-    + out (bm·bn) floats, h = padded max layer width — ~10.5 MB at the
-    paper's 2048-wide nets with the default (256, 512) blocks."""
+    set: x block (bm·h) + weight slab (h·bn) + out (bm·bn), each double
+    -buffered, + 2 activation buffers (bm·h) floats + the body's (bm·h)
+    temporaries, h = padded max layer width.  At the paper's 2048-wide
+    nets with the default (256, 512) blocks the TPU compiler needs between
+    24 and 28 MiB of scoped VMEM (tests/test_tpu_compile.py compiles it)."""
     assert len(ws) == len(bs) and len(ws) >= 1
     return _fused_mlp_vjp(bm, bk, bn, interpret)(x, tuple(ws), tuple(bs))

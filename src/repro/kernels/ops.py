@@ -1,8 +1,9 @@
 """Public jit'd entry points for the Pallas kernels.
 
-On TPU the Pallas path is used; elsewhere (this CPU container) the pure-XLA
-fallback keeps semantics identical, and ``interpret=True`` forces the
-Pallas kernel body to execute in Python for validation.
+On TPU the Pallas path is used; elsewhere the pure-XLA fallback keeps
+semantics identical.  ``flash_attention(interpret=True)`` forces its Pallas
+kernel body to execute in Python for validation; the dense aliases follow
+``dispatch`` (interpret mode only under its ``force_interpret()`` hook).
 """
 from __future__ import annotations
 
@@ -19,16 +20,16 @@ def _on_tpu() -> bool:
     return _dispatch.on_tpu()
 
 
-def fused_dense_relu(x: jnp.ndarray, w: jnp.ndarray, b: jnp.ndarray,
-                     *, interpret: Optional[bool] = None) -> jnp.ndarray:
+def fused_dense_relu(x: jnp.ndarray, w: jnp.ndarray,
+                     b: jnp.ndarray) -> jnp.ndarray:
     """relu(x @ w + b); x may have leading batch dims (flattened to M).
     Thin alias over ``dispatch.dense`` (the single dispatch point)."""
-    return _dispatch.dense(x, w, b, relu=True, interpret=bool(interpret))
+    return _dispatch.dense(x, w, b, relu=True)
 
 
-def fused_dense(x: jnp.ndarray, w: jnp.ndarray, b: jnp.ndarray,
-                *, interpret: Optional[bool] = None) -> jnp.ndarray:
-    return _dispatch.dense(x, w, b, relu=False, interpret=bool(interpret))
+def fused_dense(x: jnp.ndarray, w: jnp.ndarray,
+                b: jnp.ndarray) -> jnp.ndarray:
+    return _dispatch.dense(x, w, b, relu=False)
 
 
 def flash_attention(q, k, v, *, causal: bool = True,

@@ -10,16 +10,12 @@ from __future__ import annotations
 from typing import Optional, Tuple
 
 import jax
+from jax.sharding import AxisType
 
 
 def _make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
-    """`jax.make_mesh` with explicit Auto axis types where the installed
-    jax supports them (>= 0.5); older versions have no AxisType and their
-    meshes are implicitly Auto already."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes, axis_types=(axis_type.Auto,) * len(axes))
+    """`jax.make_mesh` with every axis Auto (GSPMD propagates shardings)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

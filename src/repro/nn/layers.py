@@ -39,7 +39,7 @@ def mlp_init(rng, in_dim: int, hidden: Sequence[int], out_dim: int, dtype=jnp.fl
 
 
 def mlp_apply(params, x, activation=jax.nn.relu,
-              use_fused: Optional[bool] = None, interpret: bool = False):
+              use_fused: Optional[bool] = None, mesh=None):
     """Plain MLP: hidden layers with `activation`, linear final layer.
 
     ``use_fused`` routes every layer through the Pallas fused
@@ -48,7 +48,8 @@ def mlp_apply(params, x, activation=jax.nn.relu,
     see kernels/dispatch.py), ``True``/``False`` force it.  The fused
     kernels hard-wire ReLU, so a non-ReLU ``activation`` raises when
     fusion was explicitly requested and silently takes the unfused path
-    on auto (it is never ignored).
+    on auto (it is never ignored).  ``mesh``: the devices the calling
+    program spans, for the kernel route (see kernels/dispatch.py).
     """
     layers = params["layers"]
     from repro.kernels import dispatch as D
@@ -58,29 +59,28 @@ def mlp_apply(params, x, activation=jax.nn.relu,
                 "mlp_apply(use_fused=True) supports only jax.nn.relu — the "
                 f"fused kernel hard-wires the ReLU epilogue; got {activation!r}. "
                 "Pass use_fused=None/False to use the unfused path.")
-        # non-ReLU: always the unfused path, interpret included — there is
+        # non-ReLU: always the unfused path, interpret hook included — there is
         # no kernel for this activation, so it is honored, never replaced
-    elif D.kernel_route_active(use_fused, interpret):
+    elif D.kernel_route_active(use_fused):
         for p in layers[:-1]:
             x = D.dense(x, p["w"], p["b"], relu=True, use_fused=use_fused,
-                        interpret=interpret)
+                        mesh=mesh)
         return D.dense(x, layers[-1]["w"], layers[-1]["b"], relu=False,
-                       use_fused=use_fused, interpret=interpret)
+                       use_fused=use_fused, mesh=mesh)
     for p in layers[:-1]:
         x = activation(dense_apply(p, x))
     return dense_apply(layers[-1], x)
 
 
 def mlp_apply_chained(params, x, use_fused: Optional[bool] = None,
-                      interpret: bool = False):
+                      mesh=None):
     """Inference-only MLP forward (hidden ReLU, linear head) through the
     layer-chained megakernel on the fused route: activations stay in VMEM
     across layers instead of one HBM round-trip per layer.  Differentiable
     too (the megakernel's VJP re-runs the fused_dense chain), but training
     should prefer ``mlp_apply`` — its per-layer backward is cheaper."""
     from repro.kernels import dispatch as D
-    return D.mlp_chain(params["layers"], x, use_fused=use_fused,
-                       interpret=interpret)
+    return D.mlp_chain(params["layers"], x, use_fused=use_fused, mesh=mesh)
 
 
 # ---------------------------------------------------------------------------

@@ -77,13 +77,14 @@ def test_mlp_apply_auto_falls_back_for_non_relu(rng):
     want = h @ params["layers"][-1]["w"] + params["layers"][-1]["b"]
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-6, atol=1e-6)
-    # even under the interpret hook / explicit interpret=True the fallback
-    # holds — the activation must never be replaced by the kernel's ReLU
+    # even under the interpret hook, on auto or pinned to jnp, the
+    # fallback holds — the activation must never be replaced by the
+    # kernel's ReLU
     with D.force_interpret():
         got2 = L.mlp_apply(params, x, activation=jnp.tanh)
+        got3 = L.mlp_apply(params, x, activation=jnp.tanh, use_fused=False)
     np.testing.assert_allclose(np.asarray(got2), np.asarray(want),
                                rtol=1e-6, atol=1e-6)
-    got3 = L.mlp_apply(params, x, activation=jnp.tanh, interpret=True)
     np.testing.assert_allclose(np.asarray(got3), np.asarray(want),
                                rtol=1e-6, atol=1e-6)
 
@@ -92,8 +93,9 @@ def test_mlp_apply_fused_interpret_parity(rng):
     params = L.mlp_init(jax.random.PRNGKey(1), 12, [24, 24], 6)
     x = jnp.asarray(rng.normal(size=(7, 12)), jnp.float32)
     want = L.mlp_apply(params, x)
-    got = L.mlp_apply(params, x, use_fused=True, interpret=True)
-    chained = L.mlp_apply_chained(params, x, use_fused=True, interpret=True)
+    with D.force_interpret():
+        got = L.mlp_apply(params, x, use_fused=True)
+        chained = L.mlp_apply_chained(params, x, use_fused=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-4, atol=1e-5)
     np.testing.assert_allclose(np.asarray(chained), np.asarray(want),

@@ -347,6 +347,39 @@ def test_train_parity_sharded(model, mesh, tiny_gan_cfg, small_dataset):
 
 
 @multidevice
+def test_kernel_route_parity_sharded(model, mesh, tiny_gan_cfg,
+                                     small_dataset):
+    """The Pallas route under a task mesh (each kernel call wrapped in
+    shard_map, as a multi-chip program requires) == single device, for
+    data-parallel training and sharded exploration.  Interpret mode here;
+    tests/test_tpu_compile.py compiles the same programs for four chips."""
+    import dataclasses
+
+    from repro.core.train import train_gan
+    from repro.kernels import dispatch as D
+
+    cfg = dataclasses.replace(tiny_gan_cfg(model, batch_size=32),
+                              use_fused=True)
+    ds = small_dataset(model, n=128)
+    tasks = generate_tasks(model, 6, seed=2)
+    with D.force_interpret():
+        base = train_gan(model, ds, cfg, iters=1, seed=0)
+        with shard.task_mesh(mesh):
+            sharded = train_gan(model, ds, cfg, iters=1, seed=0)
+        eng = GANDSE(model, cfg,
+                     ExplorerConfig(prob_threshold=0.1, max_candidates=128))
+        eng.attach(ds, base.g_params)
+        single = eng.explore_batch(tasks, seed=7)
+        with shard.task_mesh(mesh):
+            multi = eng.explore_batch(tasks, seed=7)
+    for la, lb in zip(jax.tree.leaves(base.g_params),
+                      jax.tree.leaves(sharded.g_params)):
+        np.testing.assert_allclose(np.asarray(la), np.asarray(lb),
+                                   rtol=2e-4, atol=1e-6)
+    _assert_results_equal("kernel route", single, multi)
+
+
+@multidevice
 def test_train_falls_back_when_batch_does_not_divide(model, mesh,
                                                      tiny_gan_cfg,
                                                      small_dataset):
@@ -354,7 +387,8 @@ def test_train_falls_back_when_batch_does_not_divide(model, mesh,
     cfg = tiny_gan_cfg(model, batch_size=30)   # 30 % 4 != 0
     ds = small_dataset(model, n=128)
     base = train_gan(model, ds, cfg, iters=1, seed=0)
-    with shard.task_mesh(mesh):
+    with shard.task_mesh(mesh), pytest.warns(RuntimeWarning,
+                                             match="training unsharded"):
         sharded = train_gan(model, ds, cfg, iters=1, seed=0)
     la, lb = jax.tree.leaves(base.g_params), jax.tree.leaves(sharded.g_params)
     for a, b in zip(la, lb):
