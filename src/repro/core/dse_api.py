@@ -27,6 +27,7 @@ from repro.core.selector import Selection, select, select_batch
 from repro.core.train import TrainState, train_gan
 from repro.dataset.generator import Dataset, DSETask, generate_dataset
 from repro.design_models.base import DesignModel
+from repro.utils import trace
 
 
 def parse_network(desc: Dict[str, float], model: DesignModel) -> np.ndarray:
@@ -125,6 +126,10 @@ class GANDSE:
         self.ds: Optional[Dataset] = None
         self.state: Optional[TrainState] = None
         self._explorer: Optional[Explorer] = None
+        #: the fused select's tile steps, and those that took the replay
+        #: branch, summed over every batch this engine explored
+        self.stats: Dict[str, int] = {"select_tiles": 0,
+                                      "select_replay_tiles": 0}
 
     # ---- training phase ----------------------------------------------------
     def train(self, n_data: int, iters: int, seed: int = 0, log_every: int = 0,
@@ -218,14 +223,16 @@ class GANDSE:
             sels = select_batch(self.model, tasks_p.net_idx, cand, valid,
                                 counts, tasks_p.lat_obj, tasks_p.pow_obj)
         else:
-            probs = self._explorer.generator_probs_device(
-                tasks_p.net_idx, tasks_p.lat_obj, tasks_p.pow_obj, seed=seeds)
+            with trace.span("dse.gfwd"):
+                probs = self._explorer.generator_probs_device(
+                    tasks_p.net_idx, tasks_p.lat_obj, tasks_p.pow_obj,
+                    seed=seeds)
             sels = fused_select_batch(
                 self.model, tasks_p.net_idx, probs,
                 self.explorer_cfg.prob_threshold,
                 self.explorer_cfg.max_candidates,
                 tasks_p.lat_obj, tasks_p.pow_obj,
-                tile=self.explorer_cfg.select_tile)
+                tile=self.explorer_cfg.select_tile, stats=self.stats)
         per_task = (time.time() - t0) / n_real
         return [
             DSEResult(sel, float(tasks.lat_obj[i]), float(tasks.pow_obj[i]),

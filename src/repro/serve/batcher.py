@@ -60,6 +60,9 @@ class MicroBatch:
     #: publish invalidated the model's cache entries, so a mismatched
     #: batch still responds but must not re-cache its (old-params) results.
     params_gen: int = 0
+    #: server-wide sequence number (stamped with ``params_gen``): the
+    #: ``batch`` id on the batch's trace spans (`repro.utils.trace`)
+    seq: int = -1
 
     @property
     def n_real(self) -> int:

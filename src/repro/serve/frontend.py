@@ -49,6 +49,7 @@ import numpy as np
 from repro.serve.batcher import MicroBatch
 from repro.serve.request import SOURCE_REJECTED, DSEResponse
 from repro.serve.server import DSEServer, _now
+from repro.utils import trace
 
 if TYPE_CHECKING:
     from repro.dataset.generator import Dataset
@@ -116,6 +117,7 @@ class ServeFrontend:
         self._listeners: List[Callable[[DSEResponse], None]] = []
         self._listener_errors = 0
         self._last_listener_error: Optional[str] = None
+        self._gc_spans = trace.GcSpans()   # py.gc spans while running
         server.on_response = self._on_response
 
     # ---- lifecycle ---------------------------------------------------------
@@ -124,6 +126,7 @@ class ServeFrontend:
             if self._running:
                 return self
             self._running, self._stopping = True, False
+        self._gc_spans.install()
         self._threads = [
             threading.Thread(target=self._former_loop, name="dse-former",
                              daemon=True),
@@ -157,6 +160,7 @@ class ServeFrontend:
                     rid, model, None, SOURCE_REJECTED,
                     error="front end stopped"))
             self._futures.clear()
+        self._gc_spans.remove()
 
     def __enter__(self) -> "ServeFrontend":
         return self.start()
@@ -261,7 +265,8 @@ class ServeFrontend:
         srv = self.server
         while True:
             with self._space:
-                batch = srv.form_batch()
+                with trace.span("dse.form"):
+                    batch = srv.form_batch()
                 if batch is not None:
                     self._space.notify_all()   # queue space freed
             if batch is not None:
@@ -290,7 +295,8 @@ class ServeFrontend:
     def _dispatch_loop(self) -> None:
         srv = self.server
         while True:
-            batch = self._prepared.get()
+            with trace.span("dse.dispatch_wait"):
+                batch = self._prepared.get()
             if batch is None:
                 break
             try:

@@ -21,6 +21,7 @@ Terminal states — every admitted request reaches exactly one:
 from __future__ import annotations
 
 import dataclasses
+import time
 from typing import Optional, Tuple
 
 import numpy as np
@@ -51,6 +52,10 @@ class DSERequest:
                                       # requests are shed at batch formation
                                       # (best effort: a request already in a
                                       # formed batch is served late instead)
+    #: perf_counter when the request was built (`DSEServer.submit`): the
+    #: start of its queue wait; serving metadata, not task identity
+    t_admit: float = dataclasses.field(default_factory=time.perf_counter,
+                                       compare=False)
 
     @property
     def key(self) -> Tuple:

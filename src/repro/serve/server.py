@@ -63,6 +63,7 @@ from repro.serve.cache import ResultCache
 from repro.serve.request import (SOURCE_CACHE, SOURCE_COALESCED,
                                  SOURCE_DISPATCH, SOURCE_FAILED,
                                  SOURCE_REJECTED, DSERequest, DSEResponse)
+from repro.utils import trace
 
 
 def _now() -> float:
@@ -138,6 +139,7 @@ class DSEServer:
         # -after-swap race; tests/test_serve_concurrency.py pins it).
         self._params_gen: Dict[str, int] = {}
         self._rng = random.Random(0x5EED)     # backoff jitter (deterministic)
+        self._batch_seq = 0   # sequence number of the next formed batch
         #: response hook for the concurrent front end (called synchronously
         #: inside _respond, i.e. under whatever lock the caller holds)
         self.on_response: Optional[Callable[[DSEResponse], None]] = None
@@ -149,6 +151,9 @@ class DSEServer:
             "degraded_entered": 0, "degraded_recovered": 0,
             "degraded_batches": 0, "probe_failures": 0,
             "stale_cache_skips": 0,
+            # summed over published rows: their execute start minus their
+            # admission (DSERequest.t_admit), perf_counter seconds
+            "queue_wait_s": 0.0,
         }
 
     # ---- registry ----------------------------------------------------------
@@ -343,9 +348,12 @@ class DSEServer:
 
     def _stamp(self, batch: Optional[MicroBatch]) -> Optional[MicroBatch]:
         """Stamp a formed batch with its model's current params generation
-        (a requeued-then-reformed batch gets a fresh stamp)."""
+        and the next batch sequence number, its trace id (a
+        requeued-then-reformed batch gets fresh stamps)."""
         if batch is not None:
             batch.params_gen = self._params_gen.get(batch.model_name, 0)
+            batch.seq = self._batch_seq
+            self._batch_seq += 1
         return batch
 
     def step(self, model_name: Optional[str] = None) -> int:
@@ -354,8 +362,9 @@ class DSEServer:
         ``model_name`` is None); returns the number of requests answered —
         shed rejections included — (0 when idle or backing off)."""
         now = _now()
-        answered = self.shed_expired(now)
-        batch = self._pop_ready(model_name, now)
+        with trace.span("dse.form"):
+            answered = self.shed_expired(now)
+            batch = self._pop_ready(model_name, now)
         if batch is None:
             return answered
         return answered + self._dispatch(batch)
@@ -407,24 +416,28 @@ class DSEServer:
         ``fail_batch``."""
         engine = self.engines[batch.model_name]
         deg = self._degraded.get(batch.model_name)
-        info = {"degraded": False, "probe": None, "elapsed": 0.0}
         t0 = time.perf_counter()
-        if deg is None:
-            results = engine.explore_tasks(batch.tasks, seed=batch.seeds)
-        elif deg["ok"] >= max(self.cfg.degrade_probe_after, 1):
-            # recovery probe: try the device route again; if it is still
-            # failing, fall back to the host route for this batch too
-            try:
+        info = {"degraded": False, "probe": None, "elapsed": 0.0,
+                "t_start": t0}
+        with trace.bind(batch=batch.seq), trace.span("dse.execute"):
+            if deg is None:
                 results = engine.explore_tasks(batch.tasks, seed=batch.seeds)
-                info["probe"] = "ok"
-            except Exception as e:
-                info["probe"] = "failed"
-                info["probe_error"] = repr(e)
+            elif deg["ok"] >= max(self.cfg.degrade_probe_after, 1):
+                # recovery probe: try the device route again; if it is
+                # still failing, fall back to the host route for this
+                # batch too
+                try:
+                    results = engine.explore_tasks(batch.tasks,
+                                                   seed=batch.seeds)
+                    info["probe"] = "ok"
+                except Exception as e:
+                    info["probe"] = "failed"
+                    info["probe_error"] = repr(e)
+                    info["degraded"] = True
+                    results = self._host_route(engine, batch)
+            else:
                 info["degraded"] = True
                 results = self._host_route(engine, batch)
-        else:
-            info["degraded"] = True
-            results = self._host_route(engine, batch)
         info["elapsed"] = time.perf_counter() - t0
         return results, info
 
@@ -452,50 +465,56 @@ class DSEServer:
         results are NOT cached: the swap already invalidated the model's
         entries, and re-inserting a Selection computed under the retired
         params would serve a stale result forever."""
-        name = batch.model_name
-        stale = batch.params_gen != self._params_gen.get(name, 0)
-        if stale:
-            self.stats["stale_cache_skips"] += 1
-        self.stats["dispatch_attempts"] += 1
-        self.stats["dispatch_s"] += info["elapsed"]
-        self.stats["batches"] += 1
-        self.stats["dispatched_rows"] += batch.n_real
-        self.stats["padded_rows"] += batch.padded_size - batch.n_real
-        self._consec_fail.pop(name, None)
-        self._backoff_until.pop(name, None)
-        deg = self._degraded.get(name)
-        if deg is not None:
-            if info["probe"] == "ok":       # device route healed
-                self._degraded.pop(name)
-                self.stats["degraded_recovered"] += 1
-            elif info["probe"] == "failed":  # still down; restart probe clock
-                deg["ok"] = 0
-                self.stats["probe_failures"] += 1
-                self.stats["degraded_batches"] += 1
-            else:
-                deg["ok"] += 1
-                self.stats["degraded_batches"] += 1
-        answered = 0
-        for i, req in enumerate(batch.requests):   # padding rows discarded
-            res: DSEResult = results[i]
-            key = req.key
-            self._attempts.pop(req.rid, None)
-            if not stale:
-                self.cache.put(key, res)
-            self._respond(DSEResponse(req.rid, name, res, SOURCE_DISPATCH,
-                                      batch.n_real,
-                                      degraded=info["degraded"],
-                                      net_idx=req.net_idx, seed=req.seed))
-            answered += 1
-            for rid in self._followers.pop(key, ()):
-                # followers are key-identical to the leader, so the
-                # leader's (net_idx, seed) is theirs too
-                self._respond(DSEResponse(rid, name, res, SOURCE_COALESCED,
+        with trace.span("dse.publish", batch=batch.seq):
+            name = batch.model_name
+            stale = batch.params_gen != self._params_gen.get(name, 0)
+            if stale:
+                self.stats["stale_cache_skips"] += 1
+            t_start = info.get("t_start")
+            if t_start is not None:
+                self.stats["queue_wait_s"] += sum(
+                    t_start - req.t_admit for req in batch.requests)
+            self.stats["dispatch_attempts"] += 1
+            self.stats["dispatch_s"] += info["elapsed"]
+            self.stats["batches"] += 1
+            self.stats["dispatched_rows"] += batch.n_real
+            self.stats["padded_rows"] += batch.padded_size - batch.n_real
+            self._consec_fail.pop(name, None)
+            self._backoff_until.pop(name, None)
+            deg = self._degraded.get(name)
+            if deg is not None:
+                if info["probe"] == "ok":       # device route healed
+                    self._degraded.pop(name)
+                    self.stats["degraded_recovered"] += 1
+                elif info["probe"] == "failed":
+                    # still down; restart the probe clock
+                    deg["ok"] = 0
+                    self.stats["probe_failures"] += 1
+                    self.stats["degraded_batches"] += 1
+                else:
+                    deg["ok"] += 1
+                    self.stats["degraded_batches"] += 1
+            answered = 0
+            for i, req in enumerate(batch.requests):   # padding rows discarded
+                res: DSEResult = results[i]
+                key = req.key
+                self._attempts.pop(req.rid, None)
+                if not stale:
+                    self.cache.put(key, res)
+                self._respond(DSEResponse(req.rid, name, res, SOURCE_DISPATCH,
                                           batch.n_real,
                                           degraded=info["degraded"],
                                           net_idx=req.net_idx, seed=req.seed))
                 answered += 1
-        return answered
+                for rid in self._followers.pop(key, ()):
+                    # followers are key-identical to the leader, so the
+                    # leader's (net_idx, seed) is theirs too
+                    self._respond(DSEResponse(
+                        rid, name, res, SOURCE_COALESCED, batch.n_real,
+                        degraded=info["degraded"], net_idx=req.net_idx,
+                        seed=req.seed))
+                    answered += 1
+            return answered
 
     def fail_batch(self, batch: MicroBatch, exc: Exception,
                    now: Optional[float] = None) -> None:
@@ -582,6 +601,11 @@ class DSEServer:
                         for m, t in self._backoff_until.items() if t > now}
         s["degraded"] = sorted(self._degraded)
         s["params_generation"] = dict(self._params_gen)
+        # each engine's own counters (GANDSE: the fused select's tile steps
+        # and replay steps)
+        s["engine_stats"] = {name: dict(e.stats)
+                             for name, e in sorted(self.engines.items())
+                             if isinstance(getattr(e, "stats", None), dict)}
         s["inflight_attempts"] = dict(self._attempts)
         def engine_route(e) -> bool:
             # the route this engine's dispatches actually take: the server

@@ -271,6 +271,62 @@ def test_caps_beyond_dense_limit_accepted():
         enumerate_candidates_batch(model.space, probs, 0.02, 1 << 26)
 
 
+def _accepting_tiles(model, probs, thresh, cap, lo, po, tile):
+    """Host recount: the tiles in which the sequential Algorithm 2 chain
+    accepts a row, for one task (float64 on MixModel's exact metrics)."""
+    cand = enumerate_candidates(model.space, probs, thresh, cap)
+    lat, pw = model.evaluate_indices(np.zeros((len(cand), 1), np.int32), cand)
+    l_opt = p_opt = 0.0
+    tiles = set()
+    for i, (lg, pg) in enumerate(zip(lat, pw)):
+        if not (np.isfinite(lg) and np.isfinite(pg)):
+            continue
+        init = l_opt == 0.0 and p_opt == 0.0
+        both = (l_opt > lo and p_opt > po) or (l_opt < lo and p_opt < po)
+        sc2 = l_opt > lo and p_opt < po
+        sc3 = p_opt > po and l_opt < lo
+        if (init or (both and lg < l_opt and pg < p_opt)
+                or (not both and sc2 and lg < l_opt and pg < po)
+                or (not both and not sc2 and sc3 and pg < p_opt
+                    and lg < lo)):
+            l_opt, p_opt = lg, pg
+            tiles.add(i // tile)
+    return tiles
+
+
+@pytest.mark.parametrize("name,tile", [("plain", 4), ("plain", 16),
+                                       ("ties", 8), ("holes", 4)])
+def test_tile_and_replay_counters(name, tile):
+    """The program counts ceil(max(total) / tile) tile steps per call, and
+    as replay steps exactly those in which some task's chain accepts a
+    row: one task's accepting tiles, the union of them over a batch.
+    Selections do not change with the counters on."""
+    model = MODELS[name]
+    probs = _probs(model, 4, seed=21)
+    rng = np.random.default_rng(22)
+    lo = np.float64(10.0) + rng.integers(0, 20, 4)
+    po = np.float64(10.0) + rng.integers(0, 20, 4)
+    net = np.zeros((4, 1), np.int32)
+    for rows in ([0], [1], [0, 1, 2, 3]):
+        stats = {"select_tiles": 0, "select_replay_tiles": 0}
+        sels = fused_select_batch(model, net[rows], probs[rows], 0.05, 256,
+                                  lo[rows], po[rows], tile=tile, stats=stats)
+        plain = fused_select_batch(model, net[rows], probs[rows], 0.05, 256,
+                                   lo[rows], po[rows], tile=tile)
+        for a, b in zip(sels, plain):
+            _assert_same(a, b)
+        total = max(s.n_candidates for s in sels)
+        assert stats["select_tiles"] == -(-total // tile)
+        assert 0 <= stats["select_replay_tiles"] <= stats["select_tiles"]
+        want = set().union(*(_accepting_tiles(
+            model, probs[t], 0.05, 256, lo[t], po[t], tile) for t in rows))
+        assert stats["select_replay_tiles"] == len(want)
+    # the counts add up over calls
+    fused_select_batch(model, net, probs, 0.05, 256, lo, po, tile=tile,
+                       stats=stats)
+    assert stats["select_tiles"] == 2 * -(-total // tile)
+
+
 @multidevice
 def test_fused_mesh_parity():
     """Task-sharded fused run == single-device fused run, bit-identical
