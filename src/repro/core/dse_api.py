@@ -126,10 +126,12 @@ class GANDSE:
         self.ds: Optional[Dataset] = None
         self.state: Optional[TrainState] = None
         self._explorer: Optional[Explorer] = None
-        #: the fused select's tile steps, and those that took the replay
-        #: branch, summed over every batch this engine explored
+        #: the fused select's tile steps, those that took the replay
+        #: branch and those decoded without a gather, summed over every
+        #: batch this engine explored
         self.stats: Dict[str, int] = {"select_tiles": 0,
-                                      "select_replay_tiles": 0}
+                                      "select_replay_tiles": 0,
+                                      "select_gather_free_tiles": 0}
 
     # ---- training phase ----------------------------------------------------
     def train(self, n_data: int, iters: int, seed: int = 0, log_every: int = 0,

@@ -19,7 +19,17 @@ over fixed-size candidate *tiles*:
   (T, tile, n_dims) was ~half the route's wall time) and the full
   tensor is never materialized, so peak candidate memory is
   O(T * tile * n_dims) at ANY cap;
-- the jnp oracle scores the tile;
+- the digits become configuration values.  Where every choice group has
+  at most ``SELECT_CHAIN_MAX`` choices (every shipped design space), no
+  gather runs inside the loop: once per call each task's kept choices
+  become a (T, max_group, n_dims) float32 value table, and each dim's
+  (T, tile) digit plane picks its value by an unrolled compare-select
+  chain over that dim's slots.  Wider groups take the value through the
+  per-task slot table and the constant choice table, two gathers a
+  tile.  Both give the very float32 values ``values_from_indices_jax``
+  gives, so the oracle sees identical inputs;
+- the model's float32 ``evaluate_jax`` scores the tile, with the
+  network's values converted once per call;
 - an exact fast-forward of the Algorithm-2 update chain folds the tile
   into the running per-task winner.
 
@@ -77,6 +87,20 @@ from repro.utils import trace
 #: fit where the dense route stops at 2**20
 FUSED_TILE = 1024
 
+#: widest choice group decoded by a compare-select chain: the chain costs
+#: (n - 1) selects a dim per candidate, unrolled into both the tile loop
+#: and its replay branch, so it bounds the traced program (and its compile
+#: time) at 31 selects a dim; every shipped space stays far below (im2col
+#: 7, DNNWeaver 8, tpu_mesh 7).  Wider groups, up to the route's 1024,
+#: keep the table gather.  A bound on program size, not a tuned speed.
+SELECT_CHAIN_MAX = 32
+
+
+def _gather_free(space: ConfigSpace) -> bool:
+    """Whether the program decodes tiles by compare-select chains (no
+    gather in the tile loop) rather than by table gathers."""
+    return space.max_group_size <= SELECT_CHAIN_MAX
+
 
 def _fused_batch(model: DesignModel, space: ConfigSpace, tile: int):
     """Build the jitted enumerate->score->select program for one
@@ -85,21 +109,21 @@ def _fused_batch(model: DesignModel, space: ConfigSpace, tile: int):
     masks_core, radix_core = _enum_core(space)
     rows = jnp.arange(tile, dtype=jnp.int32)
     n_dims = space.n_dims
+    gather_free = _gather_free(space)
 
     def radix_add(base, add, counts):
-        # mixed-radix add with the last dim least significant (itertools
-        # .product order, same radices as `unravel`); both addends are
-        # digit-wise < counts so the ripple carry is at most 1, and the
-        # dropped carry-out wraps mod prod(counts) exactly like the
-        # divmod form does for indices past the raw product
-        digits = []
-        carry = jnp.zeros(jnp.broadcast_shapes(base.shape[:-1],
-                                               add.shape[:-1]), jnp.int32)
+        # mixed-radix add of per-dim digit planes with the last dim least
+        # significant (itertools.product order, same radices as `unravel`);
+        # both addends are digit-wise < counts so the ripple carry is at
+        # most 1, and the dropped carry-out wraps mod prod(counts) exactly
+        # like the divmod form does for indices past the raw product
+        digits = [None] * n_dims
+        carry = jnp.int32(0)
         for d in range(n_dims - 1, -1, -1):
-            s = base[..., d] + add[..., d] + carry
-            carry = (s >= counts[..., d]).astype(jnp.int32)
-            digits.append(s - carry * counts[..., d])
-        return jnp.stack(digits[::-1], axis=-1)
+            s = base[d] + add[d] + carry
+            carry = (s >= counts[d]).astype(jnp.int32)
+            digits[d] = s - carry * counts[d]
+        return digits
 
     def fold_tile(lo, po, lat, pw, valid, j0, l_opt, p_opt, chosen):
         # exact Algorithm-2 fold of one task's tile (see module docstring):
@@ -135,24 +159,53 @@ def _fused_batch(model: DesignModel, space: ConfigSpace, tile: int):
         return l_opt, p_opt, chosen
 
     def run(probs, thresh, cap, net_idx, lo, po):
+        t = probs.shape[0]
         keep, counts, total = masks_core(probs, thresh, cap)
         table, stride = radix_core(keep, counts)
         n_tiles = (jnp.max(total) + (tile - 1)) // tile   # device: no sync
-        # the ONLY divmod decodes, once per call: in-tile offset digits
-        # (T, tile, n_dims) and the per-tile-step digit increment (T, n_dims)
-        off_dig = (rows[None, :, None] // stride[:, None, :]) \
-            % counts[:, None, :]
-        step_dig = (jnp.int32(tile) // stride) % counts
+        cnt = [counts[:, d] for d in range(n_dims)]
+        cnt_rows = [c[:, None] for c in cnt]
+        # the ONLY divmod decodes, once per call: the in-tile offset digits
+        # (n_dims planes of (T, tile), lane-dense) and the per-tile-step
+        # digit increment (T,) per dim.  The barrier keeps XLA from sinking
+        # the cheap-looking iota divmod back into the loop body (the TPU
+        # compiler does, once no gather consumes it): integer division in
+        # every tile step, and a program ten times slower to compile
+        off_dig = jax.lax.optimization_barrier(
+            [(rows[None, :] // stride[:, d, None]) % cnt_rows[d]
+             for d in range(n_dims)])
+        step_dig = [(jnp.int32(tile) // stride[:, d]) % cnt[d]
+                    for d in range(n_dims)]
+        # loop-invariant values, once per call: the network's and, on the
+        # gather-free side, each task's kept choices as float32 values
+        # vals[t, k, d] = choices_d[table[t, d, k]] (one small gather)
+        net_vals = model.net_space.values_from_indices_jax(net_idx)[:, None, :]
+        if gather_free:
+            vals = space.values_from_indices_jax(table.transpose(0, 2, 1))
+
+        def decode(digits):
+            # a tile's configuration values (T, tile, n_dims) from its
+            # per-dim digit planes.  Gather-free: an unrolled compare-select
+            # chain over the dim's slots picks vals[t, digit, d] — the very
+            # float32 that the table gather then the choice gather return.
+            if gather_free:
+                cols = []
+                for d, dim in enumerate(space.dims):
+                    v = jnp.broadcast_to(vals[:, 0, d, None], (t, tile))
+                    for k in range(1, dim.n):
+                        v = jnp.where(digits[d] == k, vals[:, k, d, None], v)
+                    cols.append(v)
+                return jnp.stack(cols, axis=-1)
+            cand = jnp.take_along_axis(table, jnp.stack(digits, axis=1),
+                                       axis=-1).transpose(0, 2, 1)
+            return space.values_from_indices_jax(cand.astype(jnp.int32))
 
         def decode_and_score(base_dig):
             # the dense `unravel` digit arithmetic on a tile-sized window,
             # via divmod-free incremental add of the tile-base digits
-            digit = radix_add(base_dig[:, None, :], off_dig,
-                              counts[:, None, :])
-            cand = jnp.take_along_axis(table, digit.transpose(0, 2, 1),
-                                       axis=-1).transpose(0, 2, 1) \
-                .astype(jnp.int32)
-            lat, pw = model.evaluate_jax_indices(net_idx[:, None, :], cand)
+            digits = radix_add([b[:, None] for b in base_dig], off_dig,
+                               cnt_rows)
+            lat, pw = model.evaluate_jax(net_vals, decode(digits))
             return lat.astype(jnp.float32), pw.astype(jnp.float32)
 
         def tile_step(k, carry):
@@ -185,11 +238,11 @@ def _fused_batch(model: DesignModel, space: ConfigSpace, tile: int):
             def replay(c):
                 # recompute the tile INSIDE the rare branch: handing latf/
                 # pwf to lax.cond as operands would force them (and the
-                # whole f64 oracle chain) to materialize every tile,
-                # breaking the common path's single fusion — recomputing
-                # from the (T, n_dims) carry digits keeps the cond's
-                # operands tiny and costs one extra oracle pass on the
-                # handful of accepting tiles
+                # whole float32 decode and oracle chain) to materialize
+                # every tile, breaking the common path's single fusion —
+                # recomputing from the (T,) carry digit planes keeps the
+                # cond's operands tiny and costs one extra decode and
+                # oracle pass on each accepting tile step
                 lat2, pw2 = decode_and_score(base_dig)
                 return jax.vmap(
                     fold_tile, in_axes=(0, 0, 0, 0, 0, None, 0, 0, 0))(
@@ -199,13 +252,12 @@ def _fused_batch(model: DesignModel, space: ConfigSpace, tile: int):
             l_opt, p_opt, chosen = jax.lax.cond(
                 hit, replay, lambda c: c, (l_opt, p_opt, chosen))
             return (l_opt, p_opt, chosen,
-                    radix_add(base_dig, step_dig, counts),
+                    radix_add(base_dig, step_dig, cnt),
                     replays + hit.astype(jnp.int32))
 
-        t = probs.shape[0]
         carry0 = (jnp.zeros(t, jnp.float32), jnp.zeros(t, jnp.float32),
                   jnp.full((t,), -1, jnp.int32),
-                  jnp.zeros((t, space.n_dims), jnp.int32), jnp.int32(0))
+                  [jnp.zeros(t, jnp.int32)] * n_dims, jnp.int32(0))
         _, _, chosen, _, replays = jax.lax.fori_loop(0, n_tiles, tile_step,
                                                      carry0)
         # winner configs from the same mixed radix; rows with chosen < 0
@@ -245,8 +297,10 @@ def fused_select_batch(
     bound becomes a deterministic all-reduce).
 
     ``stats``, when given, gains the call's tile steps (``select_tiles``,
-    ceil(max(total) / tile)) and those that took the replay branch
-    (``select_replay_tiles``), fetched with the winners in one transfer.
+    ceil(max(total) / tile)), those that took the replay branch
+    (``select_replay_tiles``), fetched with the winners in one transfer,
+    and those decoded without a gather (``select_gather_free_tiles``: all
+    of them at max group size <= ``SELECT_CHAIN_MAX``, else none).
     """
     assert model.has_jax_oracle, "fused route needs a jnp oracle"
     assert model.space.max_group_size <= 1024 and \
@@ -272,6 +326,8 @@ def fused_select_batch(
     if stats is not None:
         stats["select_tiles"] += int(n_tiles)
         stats["select_replay_tiles"] += int(replays)
+        stats["select_gather_free_tiles"] += \
+            int(n_tiles) if _gather_free(model.space) else 0
     with trace.span("dse.host_tail"):
         return selections_from_winners(model, net_idx, chosen, win_cfg,
                                        total, lo, po, noise_tol)

@@ -12,6 +12,7 @@ automatically when importable.
 """
 from __future__ import annotations
 
+import inspect
 import zlib
 from typing import Callable
 
@@ -80,15 +81,20 @@ def settings(max_examples: int = 20, deadline=None, **_kw):
 def given(*ss: _Strategy):
     def deco(f):
         n = getattr(f, "_mini_max_examples", 20)
+        # like hypothesis, the strategies fill the rightmost arguments; the
+        # leading ones stay for pytest (parametrize, fixtures)
+        params = list(inspect.signature(f).parameters.values())
+        lead = params[:len(params) - len(ss)]
 
-        def runner():
+        def runner(**kw):
             # deterministic per-test seed so failures reproduce
             rng = np.random.default_rng(zlib.crc32(f.__name__.encode()))
             for _ in range(n):
-                f(*(s.draw(rng) for s in ss))
+                f(*(kw[p.name] for p in lead), *(s.draw(rng) for s in ss))
 
-        # plain no-arg signature so pytest doesn't mistake the generated
-        # arguments for fixtures
+        # only the leading arguments in the signature, so pytest doesn't
+        # mistake the generated ones for fixtures
+        runner.__signature__ = inspect.Signature(lead)
         runner.__name__ = f.__name__
         runner.__doc__ = f.__doc__
         return runner

@@ -19,6 +19,12 @@ the float32 device chains and the float64 host loop make identical
 accept decisions — the comparisons below are exact equality, never
 almost-equal.  Small moduli force many exact ties.
 
+Each case runs through both tile decodes: the compare-select chain
+(``"chain"``: every group at most ``SELECT_CHAIN_MAX`` wide) and the
+gather fallback (``"gather"``: the same space plus one group past the
+cut-off, which the probabilities pin to a single random choice, so the
+candidate sets, tile boundaries and ties are the chain case's).
+
 The mesh test (4 fake devices, shard4 CI job) pins sharded == unsharded
 bit-identically: the task axis shards, the tile axis never does.
 """
@@ -38,16 +44,25 @@ from repro.core import shard
 from repro.core.encoding import ConfigDim, ConfigSpace
 from repro.core.explorer import (_enum_core, enumerate_candidates,
                                  enumerate_candidates_batch)
-from repro.core.fused_select import fused_select_batch
+from repro.core.fused_select import (SELECT_CHAIN_MAX, _fused_batch,
+                                     fused_select_batch)
 from repro.core.selector import select, select_batch
 from repro.design_models.base import DesignModel
+from repro.design_models.dnnweaver import DnnWeaverModel
+from repro.design_models.im2col import Im2colModel
 from repro.launch.mesh import make_host_mesh
+
+from _hlo import loop_ops
 
 N_DEV = 4
 multidevice = pytest.mark.skipif(
     len(jax.devices()) < N_DEV,
     reason=f"needs >= {N_DEV} devices; run with "
            f"XLA_FLAGS=--xla_force_host_platform_device_count={N_DEV}")
+
+
+#: a group one past the chain cut-off sends a space down the gather decode
+WIDE = SELECT_CHAIN_MAX + 1
 
 
 def _space(sizes):
@@ -61,11 +76,14 @@ class MixModel(DesignModel):
     small-integer hashes of the config values — exact in float32, so
     device (f32) and host (f64) chains agree bit-for-bit.  Small moduli
     force exact metric ties; ``inf_mod`` marks every config whose mix is
-    divisible by it infeasible (inf_mod=1 -> nothing feasible)."""
+    divisible by it infeasible (inf_mod=1 -> nothing feasible).  ``wide``
+    appends a group of ``WIDE`` choices (the gather decode)."""
 
     name = "mix"
 
-    def __init__(self, sizes, lat_mod=61.0, pw_mod=53.0, inf_mod=0.0):
+    def __init__(self, sizes, lat_mod=61.0, pw_mod=53.0, inf_mod=0.0,
+                 wide=False):
+        sizes = tuple(sizes) + ((WIDE,) if wide else ())
         self.space = _space(sizes)
         self.net_space = ConfigSpace(dims=(ConfigDim("n", (0.0, 1.0)),))
         self._w = np.arange(1, len(sizes) + 1, dtype=np.float64) * 3.0 + 2.0
@@ -94,9 +112,20 @@ def _probs(model, n_tasks, seed, peak=0.9):
     rng = np.random.default_rng(seed)
     cols = []
     for dim in model.space.dims:
+        if dim.n == WIDE:
+            break
         p = rng.dirichlet(np.ones(len(dim.choices)), size=n_tasks)
         cols.append(p / p.max(axis=1, keepdims=True) * peak)
-    return np.concatenate(cols, axis=1).astype(np.float32)
+    return _widen(model, np.concatenate(cols, axis=1), rng, peak)
+
+
+def _widen(model, probs, rng, peak=0.9):
+    """Append a wide model's last group: `peak` on one random choice a
+    task, 0 elsewhere, so it employs exactly that choice."""
+    if model.space.dims[-1].n == WIDE:
+        pin = np.eye(WIDE)[rng.integers(0, WIDE, probs.shape[0])] * peak
+        probs = np.concatenate([probs, pin], axis=1)
+    return probs.astype(np.float32)
 
 
 def _routes(model, probs, thresh, cap, lo, po, tile):
@@ -126,12 +155,13 @@ def _assert_same(a, b):
     assert a.latency == b.latency and a.power == b.power   # exact, not close
 
 
-# three fixed models so jit caches are reused across examples
-MODELS = {
-    "plain": MixModel((5, 4, 3, 4)),
-    "ties": MixModel((6, 5, 4), lat_mod=7.0, pw_mod=5.0),
-    "holes": MixModel((4, 4, 4, 3), inf_mod=7.0),
-}
+# three fixed models per decode so jit caches are reused across examples
+MODELS = {decode: {
+    "plain": MixModel((5, 4, 3, 4), wide=wide),
+    "ties": MixModel((6, 5, 4), lat_mod=7.0, pw_mod=5.0, wide=wide),
+    "holes": MixModel((4, 4, 4, 3), inf_mod=7.0, wide=wide),
+} for decode, wide in (("chain", False), ("gather", True))}
+DECODES = pytest.mark.parametrize("decode", sorted(MODELS))
 
 
 # ---------------------------------------------------------------------------
@@ -203,12 +233,13 @@ def test_tiled_enumeration_property(sizes, seed, tile):
 # ---------------------------------------------------------------------------
 # Selection parity: fused == dense == host
 # ---------------------------------------------------------------------------
-@given(st.sampled_from(sorted(MODELS)), st.integers(0, 2 ** 31 - 1),
+@DECODES
+@given(st.sampled_from(sorted(MODELS["chain"])), st.integers(0, 2 ** 31 - 1),
        st.sampled_from([0.02, 0.2, 0.5]), st.sampled_from([4, 8, 16]),
        st.floats(2.0, 50.0), st.floats(2.0, 40.0))
 @settings(max_examples=20, deadline=None)
-def test_fused_dense_host_parity(name, seed, thresh, tile, lo0, po0):
-    model = MODELS[name]
+def test_fused_dense_host_parity(decode, name, seed, thresh, tile, lo0, po0):
+    model = MODELS[decode][name]
     probs = _probs(model, 4, seed)
     rng = np.random.default_rng(seed + 1)
     lo = np.float64(lo0) + rng.integers(0, 8, 4)    # integer-valued: exact
@@ -221,12 +252,14 @@ def test_fused_dense_host_parity(name, seed, thresh, tile, lo0, po0):
     assert len(counts) >= 1   # ragged batches occur across examples
 
 
-def test_all_ties_first_candidate_wins_across_tiles():
+@DECODES
+def test_all_ties_first_candidate_wins_across_tiles(decode):
     """Every candidate identical -> Algorithm 2 accepts only the first
     finite row; a tile reduction that re-orders within a tile (or lets a
     later tile overwrite an equal carry) breaks this."""
-    model = MixModel((4, 4, 4), lat_mod=1.0, pw_mod=1.0)   # all (1+s%1)=1.0
-    probs = np.full((2, 12), 0.9, np.float32)
+    model = MixModel((4, 4, 4), lat_mod=1.0, pw_mod=1.0,   # all (1+s%1)=1.0
+                     wide=decode == "gather")
+    probs = _widen(model, np.full((2, 12), 0.9), np.random.default_rng(1))
     lo = np.array([10.0, 0.5])      # satisfied and unsatisfied regimes
     po = np.array([10.0, 0.5])
     for tile in (3, 4, 64):
@@ -234,14 +267,16 @@ def test_all_ties_first_candidate_wins_across_tiles():
         for f, d, h in zip(fused, dense, host):
             _assert_same(f, d)
             _assert_same(f, h)
-            np.testing.assert_array_equal(f.cfg_idx, [0, 0, 0])
+            np.testing.assert_array_equal(f.cfg_idx[:3], [0, 0, 0])
 
 
-def test_first_feasible_mid_tile_and_zero_feasible():
+@DECODES
+def test_first_feasible_mid_tile_and_zero_feasible(decode):
     """Leading-infeasible runs (winner sits mid-tile / in a later tile)
     and fully infeasible tasks (cfg_idx None, counts still reported)."""
-    holes = MixModel((4, 4, 4), inf_mod=2.0)       # ~half the grid infeasible
-    dead = MixModel((4, 4, 4), inf_mod=1.0)        # nothing feasible
+    wide = decode == "gather"
+    holes = MixModel((4, 4, 4), inf_mod=2.0, wide=wide)   # ~half infeasible
+    dead = MixModel((4, 4, 4), inf_mod=1.0, wide=wide)    # nothing feasible
     probs = _probs(holes, 3, seed=5)
     lo = np.array([20.0, 3.0, 40.0])
     po = np.array([20.0, 3.0, 40.0])
@@ -257,10 +292,11 @@ def test_first_feasible_mid_tile_and_zero_feasible():
         _assert_same(f, h)
 
 
-def test_caps_beyond_dense_limit_accepted():
+@DECODES
+def test_caps_beyond_dense_limit_accepted(decode):
     """The fused route takes caps past the dense materialization bound
     (2**20); the dense route still refuses them."""
-    model = MODELS["plain"]
+    model = MODELS[decode]["plain"]
     probs = _probs(model, 2, seed=9)
     lo = po = np.array([20.0, 20.0])
     net = np.zeros((2, 1), np.int32)
@@ -294,21 +330,24 @@ def _accepting_tiles(model, probs, thresh, cap, lo, po, tile):
     return tiles
 
 
+@DECODES
 @pytest.mark.parametrize("name,tile", [("plain", 4), ("plain", 16),
                                        ("ties", 8), ("holes", 4)])
-def test_tile_and_replay_counters(name, tile):
-    """The program counts ceil(max(total) / tile) tile steps per call, and
-    as replay steps exactly those in which some task's chain accepts a
-    row: one task's accepting tiles, the union of them over a batch.
-    Selections do not change with the counters on."""
-    model = MODELS[name]
+def test_tile_and_replay_counters(decode, name, tile):
+    """The program counts ceil(max(total) / tile) tile steps per call, as
+    replay steps exactly those in which some task's chain accepts a row
+    (one task's accepting tiles, the union of them over a batch), and as
+    gather-free steps all of them on the chain decode, none on the
+    gather decode.  Selections do not change with the counters on."""
+    model = MODELS[decode][name]
     probs = _probs(model, 4, seed=21)
     rng = np.random.default_rng(22)
     lo = np.float64(10.0) + rng.integers(0, 20, 4)
     po = np.float64(10.0) + rng.integers(0, 20, 4)
     net = np.zeros((4, 1), np.int32)
     for rows in ([0], [1], [0, 1, 2, 3]):
-        stats = {"select_tiles": 0, "select_replay_tiles": 0}
+        stats = {"select_tiles": 0, "select_replay_tiles": 0,
+                 "select_gather_free_tiles": 0}
         sels = fused_select_batch(model, net[rows], probs[rows], 0.05, 256,
                                   lo[rows], po[rows], tile=tile, stats=stats)
         plain = fused_select_batch(model, net[rows], probs[rows], 0.05, 256,
@@ -321,17 +360,59 @@ def test_tile_and_replay_counters(name, tile):
         want = set().union(*(_accepting_tiles(
             model, probs[t], 0.05, 256, lo[t], po[t], tile) for t in rows))
         assert stats["select_replay_tiles"] == len(want)
+        assert stats["select_gather_free_tiles"] == (
+            stats["select_tiles"] if decode == "chain" else 0)
     # the counts add up over calls
     fused_select_batch(model, net, probs, 0.05, 256, lo, po, tile=tile,
                        stats=stats)
     assert stats["select_tiles"] == 2 * -(-total // tile)
+    assert stats["select_gather_free_tiles"] == (
+        stats["select_tiles"] if decode == "chain" else 0)
+
+
+@pytest.mark.parametrize("model_cls", [Im2colModel, DnnWeaverModel])
+def test_shipped_models_decode_gather_free(model_cls):
+    """Both benchmarked design spaces take the chain decode: every tile
+    step counts as gather-free."""
+    model = model_cls()
+    probs = _probs(model, 3, seed=31)
+    net = np.zeros((3, model.net_space.n_dims), np.int32)
+    stats = {"select_tiles": 0, "select_replay_tiles": 0,
+             "select_gather_free_tiles": 0}
+    fused_select_batch(model, net, probs, 0.05, 512, np.full(3, 1e-3),
+                       np.full(3, 5.0), tile=64, stats=stats)
+    assert stats["select_tiles"] >= 2
+    assert stats["select_gather_free_tiles"] == stats["select_tiles"]
+
+
+@pytest.mark.parametrize("model,gather_free", [
+    (Im2colModel(), True), (DnnWeaverModel(), True),
+    (MixModel((5, 4, 3), wide=True), False)],
+    ids=["im2col", "dnnweaver", "wide"])
+def test_tile_loop_holds_no_candidate_gather(model, gather_free):
+    """The compiled tile loop (its body and the replay branch) gathers
+    nothing candidate-sized at T = 64, tile = 1024: no (T, tile, n_dims)
+    index lookup, no (T, tile) value lookup.  Only the once-per-call
+    tables gather.  A space past the chain cut-off keeps its gathers,
+    which shows the check sees them."""
+    t, tile = 64, 1024
+    f32, i32 = jnp.float32, jnp.int32
+    hlo = _fused_batch(model, model.space, tile).lower(
+        jax.ShapeDtypeStruct((t, model.space.onehot_width), f32),
+        jax.ShapeDtypeStruct((), f32), jax.ShapeDtypeStruct((), i32),
+        jax.ShapeDtypeStruct((t, model.net_space.n_dims), i32),
+        jax.ShapeDtypeStruct((t,), f32), jax.ShapeDtypeStruct((t,), f32),
+    ).compile().as_text()
+    big = [g for g in loop_ops(hlo, "gather") if g[1] >= t * tile]
+    assert (big == []) == gather_free, big
 
 
 @multidevice
-def test_fused_mesh_parity():
+@DECODES
+def test_fused_mesh_parity(decode):
     """Task-sharded fused run == single-device fused run, bit-identical
     (the tile axis is never sharded; max(total) becomes an all-reduce)."""
-    model = MixModel((6, 5, 4, 3))
+    model = MixModel((6, 5, 4, 3), wide=decode == "gather")
     probs = _probs(model, 8, seed=13)
     rng = np.random.default_rng(14)
     lo = np.float64(10.0) + rng.integers(0, 20, 8)

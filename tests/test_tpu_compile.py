@@ -27,10 +27,13 @@ from jax.sharding import SingleDeviceSharding
 from repro.core import gan as G
 from repro.core import train as T
 from repro.core.explorer import _cached_fwd
+from repro.core.fused_select import _fused_batch
 from repro.design_models.dnnweaver import DnnWeaverModel
 from repro.design_models.im2col import Im2colModel
 from repro.kernels import dispatch as D
 from repro.kernels import fused_mlp as FM
+
+from _hlo import loop_ops
 
 MODELS = {"dnnweaver": DnnWeaverModel, "im2col": Im2colModel}
 HBM_BYTES = 16 * 2 ** 30          # one v5e chip
@@ -221,3 +224,26 @@ def test_sharded_generator_forward_compiles_on_four_chips(four_chips,
              jax.ShapeDtypeStruct((t, cfg.n_net), jnp.float32, sharding=rows),
              jax.ShapeDtypeStruct((t, cfg.n_obj), jnp.float32, sharding=rows),
              _spec(rows, keys))
+
+
+@pytest.mark.parametrize("model_name", sorted(MODELS))
+def test_fused_select_tile_loop_compiles_gather_and_divide_free(one_chip,
+                                                                model_name):
+    """The serving select at the sweep's batch (T = 64, tile = 1024): its
+    tile loop and replay branch hold no candidate-sized gather, and no
+    integer division, which the TPU compiler would otherwise sink back
+    into the loop from the once-per-call offset decode."""
+    model = MODELS[model_name]()
+    t, tile = 64, 1024
+    f32, i32 = jnp.float32, jnp.int32
+    args = _spec(one_chip, (
+        jax.ShapeDtypeStruct((t, model.space.onehot_width), f32),
+        jax.ShapeDtypeStruct((), f32), jax.ShapeDtypeStruct((), i32),
+        jax.ShapeDtypeStruct((t, model.net_space.n_dims), i32),
+        jax.ShapeDtypeStruct((t,), f32), jax.ShapeDtypeStruct((t,), f32)))
+    text = _fused_batch(model, model.space, tile).lower(*args).compile() \
+        .as_text()                     # no Pallas kernel: not `_compile`
+    for op in ("gather", "divide", "remainder"):
+        big = [(dt, n) for dt, n in loop_ops(text, op)
+               if n >= t * tile and (op == "gather" or dt == "s32")]
+        assert big == [], (op, big)

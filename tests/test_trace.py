@@ -90,6 +90,8 @@ def test_served_window_holds_nested_batch_spans(engine, tmp_path):
     counts = srv.summary()["engine_stats"][MODEL.name]
     assert 0 <= counts["select_replay_tiles"] <= counts["select_tiles"]
     assert counts["select_tiles"] >= srv.stats["batches"]
+    # DNNWeaver's groups (at most 8 choices) take the gather-free decode
+    assert counts["select_gather_free_tiles"] == counts["select_tiles"]
 
 
 def test_collector_spans_only_while_running(engine):
