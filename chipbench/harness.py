@@ -2,10 +2,11 @@
 the compile cache, the per-layer metric readers and the result line.
 
 A cell of ``BENCHMARK.json`` names a configuration (``configs/<name>.json``)
-and a traffic mix (``mixes/<name>.json``).  The mix names its driver
-(``drivers/<name>.py``); a per-layer metric ``<name>`` is read by
-``metrics/<name>.py``.  Nothing here lists cells, mixes or metrics: adding
-one is adding its file.
+and a traffic mix (``mixes/<name>.json``).  The configuration names its
+design model, whose reference oracle is ``oracles/<design_model>.py``; the
+mix names its driver (``drivers/<name>.py``); a per-layer metric
+``<name>`` is read by ``metrics/<name>.py``.  Nothing here lists cells,
+design models, mixes or metrics: adding one is adding its file.
 """
 from __future__ import annotations
 
@@ -64,6 +65,16 @@ def _load_module(path: str, name: str):
 def driver(name: str):
     return _load_module(os.path.join(HERE, "drivers", name + ".py"),
                         "chipbench_driver_" + name)
+
+
+def oracle(design_model: str):
+    """The design model's reference oracle module (see oracles/__init__.py),
+    or BenchError naming the file to add."""
+    path = os.path.join(HERE, "oracles", design_model + ".py")
+    if not os.path.isfile(path):
+        raise BenchError(f"no reference oracle for design model "
+                         f"{design_model!r}: add {os.path.relpath(path, ROOT)}")
+    return importlib.import_module("chipbench.oracles." + design_model)
 
 
 def reader(metric: str) -> Callable[[dict], Optional[float]]:

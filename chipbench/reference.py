@@ -1,8 +1,9 @@
 """Plain reference for the GANDSE cells, written from the paper and the
 configuration files alone.  It imports nothing of the program under test.
 
-- the design spaces and the float64 design-model oracles (im2col and
-  DNNWeaver), read from the configuration file;
+- the design spaces, read from the configuration file, and the float64
+  oracle of its design model, ``oracles/<design_model>.py`` (a design
+  model is added by adding its file; see ``oracles/__init__.py``);
 - the encoding of network parameters and objectives (log2, then
   standardised by the mean and std of a data set);
 - the conditional generator G as a plain float32 MLP with a softmax per
@@ -29,6 +30,8 @@ import jax
 import jax.numpy as jnp
 import ml_dtypes
 import numpy as np
+
+from chipbench import harness
 
 NOISE_TOL = 0.01          # paper section 7.2: 1% noise allowed when judging
 
@@ -66,100 +69,22 @@ class Space:
 
 
 # ---------------------------------------------------------------------------
-# design-model oracles, float64 on the host
+# the design model's oracle, float64 on the host
 # ---------------------------------------------------------------------------
-def _roofline(k: dict, net, pen, dsb, sdb, iss, wss, oss,
-              tic, toc, tow, toh, tkw, tkh):
-    """Three-stage pipelined roofline: (latency s, power W); +inf when a
-    tile does not fit its SRAM."""
-    ic, oc, ow, oh, kw, kh = (net[..., i] for i in range(6))
-    tic, toc = np.minimum(tic, ic), np.minimum(toc, oc)
-    tow, toh = np.minimum(tow, ow), np.minimum(toh, oh)
-    tkw, tkh = np.minimum(tkw, kw), np.minimum(tkh, kh)
-    cd = lambda a, b: np.ceil(a / b)
-    n_tiles = cd(ic, tic) * cd(oc, toc) * cd(ow, tow) * cd(oh, toh) \
-        * cd(kw, tkw) * cd(kh, tkh)
-    n_out_tiles = cd(oc, toc) * cd(ow, tow) * cd(oh, toh)
-    tile_macs = tic * toc * tow * toh * tkw * tkh
-    t_comp = cd(tile_macs, pen)
-    in_words = tic * tkw * tkh * tow * toh
-    w_words = tic * toc * tkw * tkh
-    t_load = cd(in_words + w_words, dsb)
-    out_words = toc * tow * toh
-    t_store = cd(out_words, sdb)
-    store_amort = t_store * (n_out_tiles / n_tiles)
-    bottleneck = np.maximum(np.maximum(t_load, t_comp), store_amort)
-    cycles = bottleneck * np.maximum(n_tiles - 1.0, 0.0) + t_load + t_comp \
-        + t_store
-    feasible = (in_words <= iss) & (w_words <= wss) & (out_words <= oss)
-    cycles = np.where(feasible, cycles, np.inf)
-    total_macs = ic * oc * ow * oh * kw * kh
-    dram_words = n_tiles * (in_words + w_words) + n_out_tiles * out_words
-    sram_words = 2.0 * total_macs + n_out_tiles * out_words
-    energy = k["E_MAC_J"] * total_macs + k["E_SRAM_J"] * sram_words \
-        + k["E_DRAM_J"] * dram_words
-    lat = cycles / k["CLOCK_HZ"]
-    p_static = (k["P_STATIC_BASE_W"] + k["P_STATIC_PE_W"] * pen
-                + k["P_STATIC_SRAM_W"] * (iss + wss + oss)
-                + k["P_STATIC_BW_W"] * (sdb + dsb))
-    with np.errstate(invalid="ignore"):
-        p_dyn = np.where(np.isfinite(lat), energy / np.maximum(lat, 1e-12),
-                         0.0)
-    power = np.where(feasible, p_static + p_dyn, np.inf)
-    return lat, power
-
-
-def _pow2floor(x):
-    return np.power(2.0, np.floor(np.log2(np.maximum(x, 1.0))))
-
-
-def _dnnweaver_tiles(net, iss, wss, oss):
-    """DNNWeaver's own greedy schedule: full kernel window, channels fit
-    the weight SRAM, a square-ish output plane fits the output SRAM, then
-    halvings until the im2col patch fits the input SRAM."""
-    ic, oc, ow, oh, kw, kh = (net[..., i] for i in range(6))
-    tkw, tkh = kw, kh
-    tic = np.maximum(_pow2floor(np.minimum(ic, wss / np.maximum(kw * kh,
-                                                                1.0))), 1.0)
-    toc = np.maximum(_pow2floor(np.minimum(
-        np.minimum(oc, oss), wss / np.maximum(tic * kw * kh, 1.0))), 1.0)
-    plane_cap = np.maximum(oss / np.maximum(toc, 1.0), 1.0)
-    tow = np.maximum(np.minimum(_pow2floor(np.sqrt(plane_cap)), ow), 1.0)
-    toh = np.maximum(np.minimum(_pow2floor(plane_cap / tow), oh), 1.0)
-    tiles = [toh, tow, tic]
-    for j in range(3):
-        patch = tiles[2] * tkw * tkh * tiles[1] * tiles[0]
-        excess = np.power(2.0, np.ceil(np.log2(
-            np.maximum(patch / np.maximum(iss, 1.0), 1.0))))
-        f = np.minimum(tiles[j], excess)
-        tiles[j] = np.maximum(tiles[j] / f, 1.0)
-    toh, tow, tic = tiles
-    return tic, toc, tow, toh, tkw, tkh
-
-
 class Oracle:
-    """(net indices, config indices) -> (latency, power), float64."""
+    """(net indices, config indices) -> (latency, power), float64, by the
+    configuration's design model in ``oracles/<design_model>.py``."""
 
     def __init__(self, cfg: dict):
-        self.kind = cfg["design_model"]
         self.k = cfg["oracle_constants"]
         self.net_space = Space(cfg["net_space"])
         self.space = Space(cfg["config_space"])
-        if self.kind not in ("im2col", "dnnweaver"):
-            raise ValueError(f"no reference oracle for {self.kind!r}")
+        self.impl = harness.oracle(cfg["design_model"])
 
     def __call__(self, net_idx, cfg_idx):
         net = self.net_space.values(net_idx)
         c = self.space.values(cfg_idx)
-        if self.kind == "im2col":
-            pen, sdb, dsb, iss, wss, oss, tic, toc, tow, toh, tkw, tkh = (
-                c[..., i] for i in range(12))
-            return _roofline(self.k, net, pen, dsb, sdb, iss, wss, oss,
-                             tic, toc, tow, toh, tkw, tkh)
-        pen, iss, wss, oss = (c[..., i] for i in range(4))
-        tiles = _dnnweaver_tiles(net, iss, wss, oss)
-        return _roofline(self.k, net, pen, self.k["FIXED_DSB"],
-                         self.k["FIXED_SDB"], iss, wss, oss, *tiles)
+        return self.impl.evaluate(self.k, net, c)
 
 
 # ---------------------------------------------------------------------------

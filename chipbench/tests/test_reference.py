@@ -1,6 +1,7 @@
 """The plain reference against the program on the CPU, at small sizes:
 they must agree exactly where both compute in float32 or float64 alike."""
 import itertools
+import os
 
 import jax
 import numpy as np
@@ -13,10 +14,15 @@ def _cfg(name):
     return harness.load_json(f"{harness.HERE}/configs/{name}.json")
 
 
-@pytest.mark.parametrize("name", ["gandse-im2col", "gandse-dnnweaver"])
-def test_oracle_matches_program(name):
+CONFIGS = harness.benchmark()["configs"]
+
+
+@pytest.mark.parametrize("entry", CONFIGS, ids=[c["name"] for c in CONFIGS])
+def test_oracle_matches_program(entry):
+    """Every configuration's reference oracle gives its program's numpy
+    oracle's numbers exactly."""
     import importlib
-    cfg = _cfg(name)
+    cfg = harness.load_json(os.path.join(harness.ROOT, entry["file"]))
     mod, cls = cfg["program_model"].split(":")
     model = getattr(importlib.import_module(mod), cls)()
     oracle = reference.Oracle(cfg)
