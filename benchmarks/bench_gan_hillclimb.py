@@ -20,15 +20,16 @@ import numpy as np
 from benchmarks.common import write_json
 from repro import configs
 from repro.configs.shapes import SHAPES
-from repro.core.dse_api import GANDSE
+from repro.core.dse_api import GANDSE, parse_network
 from repro.core.gan import GANConfig
-from repro.design_models.tpu_mesh import TpuMeshModel
+from repro.design_models.tpu_mesh import QWEN3_14B_4K, TpuMeshModel
 
 
 def gan_proposals(n_best: int = 3, step_obj: float = 0.6,
                   power_obj: float = 80e3, seeds=(0, 1, 2, 3)):
     """Train the mesh-DSE GAN and collect distinct single-pod 256-chip
-    proposals (PODS=1, DP*TP=256) for the qwen3-14b train_4k workload."""
+    proposals (REPLICAS=1, PP=1, DP*TP=256) for the qwen3-14b train_4k
+    workload."""
     model = TpuMeshModel()
     cfg = GANConfig(n_net=model.net_space.n_dims, w_critic=1.0).scaled(
         layers=3, neurons=256, batch_size=512, lr=1e-4)
@@ -36,8 +37,7 @@ def gan_proposals(n_best: int = 3, step_obj: float = 0.6,
     g.train(n_data=8000, iters=8, seed=0)
 
     # qwen3-14b train_4k: 40L x 5120, dff ~3.4x, seq 4096, batch 256
-    net = model.net_space.indices_from_values(
-        np.array([[40., 5120., 3., 4096., 256., 131072.]]))[0]
+    net = parse_network(QWEN3_14B_4K, model)
     # collect the GAN's candidate sets across noise seeds, keep only
     # single-pod 256-chip configs (our dry-run budget), rank by the
     # design model's latency
@@ -48,7 +48,8 @@ def gan_proposals(n_best: int = 3, step_obj: float = 0.6,
         cands.append(enumerate_candidates(model.space, probs, 0.1, 4096))
     cand = np.unique(np.concatenate(cands), axis=0)
     vals = model.space.values_from_indices(cand)
-    keep = (vals[:, 0] == 1) & (vals[:, 1] * vals[:, 2] == 256)
+    keep = (vals[:, 0] == 1) & (vals[:, 1] == 1) \
+        & (vals[:, 2] * vals[:, 3] == 256)
     cand, vals = cand[keep], vals[keep]
     if cand.size == 0:
         return []

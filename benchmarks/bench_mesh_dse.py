@@ -15,7 +15,7 @@ from repro.design_models.tpu_mesh import TpuMeshModel
 
 def exhaustive_best(model, net_idx, lo, po):
     space = model.space
-    # enumerate the whole mesh space (7840 configs)
+    # enumerate the whole mesh space (235,200 configs)
     idx = np.indices([d.n for d in space.dims]).reshape(space.n_dims, -1).T
     net = np.repeat(net_idx[None], idx.shape[0], axis=0)
     lat, pw = model.evaluate_indices(net, idx)

@@ -1,16 +1,15 @@
-"""Beyond-paper example: GAN-DSE searching THIS framework's parallelism
-design space (pods x dp x tp x microbatch x remat x dtype x compression)
-for a target workload, with the TPU roofline as the design model.
+"""Beyond-paper example: GAN-DSE searching a training job's parallelism
+design space (replicas x pipeline x dp x tp x ep x microbatch x remat x
+dtype x compression) for a target workload, with the TPU roofline as the
+design model.
 
   PYTHONPATH=src python examples/mesh_dse.py
 """
 import json
 
-import numpy as np
-
-from repro.core.dse_api import GANDSE
+from repro.core.dse_api import GANDSE, parse_network
 from repro.core.gan import GANConfig
-from repro.design_models.tpu_mesh import TpuMeshModel
+from repro.design_models.tpu_mesh import QWEN3_14B_4K, TpuMeshModel
 
 
 def main():
@@ -22,8 +21,7 @@ def main():
     gandse.train(n_data=8000, iters=8, log_every=4)
 
     # workload: qwen3-14b-like training job (40L x 5120, seq 4096, batch 256)
-    net = model.net_space.indices_from_values(
-        np.array([[40., 5120., 3., 4096., 256., 131072.]]))[0]
+    net = parse_network(QWEN3_14B_4K, model)
 
     # objectives: step_time <= 5 s at <= 150 kW cluster power
     result = gandse.explore(net, 5.0, 150e3)
@@ -35,10 +33,11 @@ def main():
         art = gandse.emit_config(result)
         print(json.dumps(art, indent=1))
         c = art["config"]
-        chips = int(c["PODS"] * c["DP"] * c["TP"])
-        print(f"-> launch config: {int(c['PODS'])} pod(s) x "
-              f"(data={int(c['DP'])}, model={int(c['TP'])}) = {chips} chips, "
-              f"microbatch={int(c['MICRO'])}, remat={bool(c['REMAT'])}, "
+        chips = int(c["REPLICAS"] * c["PP"] * c["DP"] * c["TP"])
+        print(f"-> launch config: {int(c['REPLICAS'])} replica(s) x "
+              f"{int(c['PP'])} stage(s) x (data={int(c['DP'])}, "
+              f"model={int(c['TP'])}, expert={int(c['EP'])}) = {chips} "
+              f"chips, microbatch={int(c['MICRO'])}, remat={bool(c['REMAT'])}, "
               f"param_bytes={int(c['BYTES_P'])}, "
               f"dcn_compression={int(c['COMPRESS'])}x")
 

@@ -55,8 +55,9 @@ MODELS = {
 
 #: Per-design-model exploration threshold (a deployment knob, §7.1.3:
 #: higher-dimension/higher-entropy spaces need a sharper cut or the
-#: candidate budget explodes) and training length (the tpu_mesh divisibility
-#: structure needs more epochs to concentrate at CPU scale).
+#: candidate budget explodes) and training length (the tpu_mesh feasibility
+#: structure, divisibility, HBM capacity and the expert axis, needs more
+#: epochs to concentrate at CPU scale; about 11% of its rows are feasible).
 MODEL_PRESETS = {
     "dnnweaver": dict(threshold=0.2, iters_mult=1, data_mult=1),
     "im2col": dict(threshold=0.3, iters_mult=1, data_mult=1),

@@ -127,11 +127,14 @@ class GANDSE:
         self.state: Optional[TrainState] = None
         self._explorer: Optional[Explorer] = None
         #: the fused select's tile steps, those that took the replay
-        #: branch and those decoded without a gather, summed over every
-        #: batch this engine explored
+        #: branch and those decoded without a gather, and the candidates
+        #: scanned and found feasible, summed over every batch this
+        #: engine explored
         self.stats: Dict[str, int] = {"select_tiles": 0,
                                       "select_replay_tiles": 0,
-                                      "select_gather_free_tiles": 0}
+                                      "select_gather_free_tiles": 0,
+                                      "select_scanned": 0,
+                                      "select_feasible": 0}
 
     # ---- training phase ----------------------------------------------------
     def train(self, n_data: int, iters: int, seed: int = 0, log_every: int = 0,

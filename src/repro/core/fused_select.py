@@ -91,8 +91,9 @@ FUSED_TILE = 1024
 #: (n - 1) selects a dim per candidate, unrolled into both the tile loop
 #: and its replay branch, so it bounds the traced program (and its compile
 #: time) at 31 selects a dim; every shipped space stays far below (im2col
-#: 7, DNNWeaver 8, tpu_mesh 7).  Wider groups, up to the route's 1024,
-#: keep the table gather.  A bound on program size, not a tuned speed.
+#: 7, DNNWeaver 8, tpu_mesh 7: its DP and EP groups).  Wider groups, up to
+#: the route's 1024, keep the table gather.  A bound on program size, not a
+#: tuned speed.
 SELECT_CHAIN_MAX = 32
 
 
@@ -209,7 +210,7 @@ def _fused_batch(model: DesignModel, space: ConfigSpace, tile: int):
             return lat.astype(jnp.float32), pw.astype(jnp.float32)
 
         def tile_step(k, carry):
-            l_opt, p_opt, chosen, base_dig, replays = carry
+            l_opt, p_opt, chosen, base_dig, replays, feasible = carry
             j0 = (k * tile).astype(jnp.int32)
             valid = (j0 + rows)[None, :] < total[:, None]
             latf, pwf = decode_and_score(base_dig)
@@ -251,21 +252,27 @@ def _fused_batch(model: DesignModel, space: ConfigSpace, tile: int):
             hit = jnp.any(upd)
             l_opt, p_opt, chosen = jax.lax.cond(
                 hit, replay, lambda c: c, (l_opt, p_opt, chosen))
+            # each task's feasible candidates, counted once a tile step
+            # from the common path's mask (a replay recounts nothing);
+            # per task, so the count stays exact int32 at any cap
             return (l_opt, p_opt, chosen,
                     radix_add(base_dig, step_dig, cnt),
-                    replays + hit.astype(jnp.int32))
+                    replays + hit.astype(jnp.int32),
+                    feasible + jnp.sum(fin, axis=1, dtype=jnp.int32))
 
         carry0 = (jnp.zeros(t, jnp.float32), jnp.zeros(t, jnp.float32),
                   jnp.full((t,), -1, jnp.int32),
-                  [jnp.zeros(t, jnp.int32)] * n_dims, jnp.int32(0))
-        _, _, chosen, _, replays = jax.lax.fori_loop(0, n_tiles, tile_step,
-                                                     carry0)
+                  [jnp.zeros(t, jnp.int32)] * n_dims, jnp.int32(0),
+                  jnp.zeros(t, jnp.int32))
+        _, _, chosen, _, replays, feasible = jax.lax.fori_loop(
+            0, n_tiles, tile_step, carry0)
         # winner configs from the same mixed radix; rows with chosen < 0
         # yield arbitrary values here and are masked by the host tail
         jw = jnp.maximum(chosen, 0)[:, None]
         digit_w = (jw // stride) % counts
         win = jnp.take_along_axis(table, digit_w[:, :, None], axis=-1)[..., 0]
-        return chosen, win.astype(jnp.int32), total, n_tiles, replays
+        return (chosen, win.astype(jnp.int32), total, n_tiles, replays,
+                feasible)
 
     return jax.jit(run)
 
@@ -300,7 +307,12 @@ def fused_select_batch(
     ceil(max(total) / tile)), those that took the replay branch
     (``select_replay_tiles``), fetched with the winners in one transfer,
     and those decoded without a gather (``select_gather_free_tiles``: all
-    of them at max group size <= ``SELECT_CHAIN_MAX``, else none).
+    of them at max group size <= ``SELECT_CHAIN_MAX``, else none); and
+    the candidates the tasks scanned (``select_scanned``, padding rows
+    included) and those the oracle found feasible (``select_feasible``,
+    finite latency and power), from the same transfer.  The batch's
+    ``dse.host_tail`` span carries the call's two candidate counts as
+    metadata.
     """
     assert model.has_jax_oracle, "fused route needs a jnp oracle"
     assert model.space.max_group_size <= 1024 and \
@@ -322,12 +334,18 @@ def fused_select_batch(
             shard.put_sharded(po.astype(np.float32)),
         )
     with trace.span("dse.sync"):
-        chosen, win_cfg, total, n_tiles, replays = jax.device_get(out)
+        chosen, win_cfg, total, n_tiles, replays, feasible = \
+            jax.device_get(out)
+    counts = {"select_tiles": int(n_tiles),
+              "select_replay_tiles": int(replays),
+              "select_gather_free_tiles":
+                  int(n_tiles) if _gather_free(model.space) else 0,
+              "select_scanned": int(np.sum(total, dtype=np.int64)),
+              "select_feasible": int(np.sum(feasible, dtype=np.int64))}
     if stats is not None:
-        stats["select_tiles"] += int(n_tiles)
-        stats["select_replay_tiles"] += int(replays)
-        stats["select_gather_free_tiles"] += \
-            int(n_tiles) if _gather_free(model.space) else 0
-    with trace.span("dse.host_tail"):
+        for k, v in counts.items():
+            stats[k] = stats.get(k, 0) + v
+    with trace.span("dse.host_tail", select_scanned=counts["select_scanned"],
+                    select_feasible=counts["select_feasible"]):
         return selections_from_winners(model, net_idx, chosen, win_cfg,
                                        total, lo, po, noise_tol)

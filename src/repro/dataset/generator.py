@@ -91,15 +91,30 @@ class DSETask:
                        pow_obj=np.atleast_1d(np.asarray(pow_obj, np.float64)))
 
 
+#: draws of one batch of rows at most before a sampler gives up: enough
+#: for a space where one row in a thousand is feasible (the DeepSeek-V3
+#: mesh preset's least feasible phases read 0.05-0.1%, its mean 1.1%)
+MAX_DRAWS = 512
+
+
+def _too_sparse(model: DesignModel, want: int, got: int, m: int):
+    return ValueError(
+        f"{model.name}: {got} of {want} feasible rows after {MAX_DRAWS} "
+        f"draws of {m}; the space is (nearly) all infeasible")
+
+
 def generate_dataset(
     model: DesignModel, n: int, seed: int = 0, oversample: float = 3.0
 ) -> Dataset:
-    """Evenly sample the design space; keep `n` feasible rows."""
+    """Evenly sample the design space; keep `n` feasible rows (at most
+    ``MAX_DRAWS`` batches of draws, else ValueError)."""
     rng = np.random.default_rng(seed)
     net_rows, cfg_rows, lats, pows = [], [], [], []
     got = 0
-    while got < n:
-        m = int(max(n * oversample, 1024))
+    m = int(max(n * oversample, 1024))
+    for _ in range(MAX_DRAWS):
+        if got >= n:
+            break
         net_idx = model.net_space.sample_indices(rng, m)
         cfg_idx = model.space.sample_indices(rng, m)
         lat, pw = model.evaluate_indices(net_idx, cfg_idx)
@@ -109,6 +124,8 @@ def generate_dataset(
         lats.append(lat[ok])
         pows.append(pw[ok])
         got += int(ok.sum())
+    if got < n:
+        raise _too_sparse(model, n, got, m)
     net_idx = np.concatenate(net_rows)[:n]
     cfg_idx = np.concatenate(cfg_rows)[:n]
     lat = np.concatenate(lats)[:n]
@@ -137,12 +154,15 @@ def generate_tasks(
     least one config meeting them): draw a net + a witness config, evaluate
     it, and relax the witness metrics by a random slack factor in `slack`.
     slack=(1.0, 1.0) yields Pareto-adjacent (hard) objectives (§7.4).
+    At most ``MAX_DRAWS`` batches of draws, else ValueError.
     """
     rng = np.random.default_rng(seed)
     net_rows, lo_rows, po_rows = [], [], []
     got = 0
-    while got < n_tasks:
-        m = max(n_tasks * 2, 512)
+    m = max(n_tasks * 2, 512)
+    for _ in range(MAX_DRAWS):
+        if got >= n_tasks:
+            break
         net_idx = model.net_space.sample_indices(rng, m)
         cfg_idx = model.space.sample_indices(rng, m)
         lat, pw = model.evaluate_indices(net_idx, cfg_idx)
@@ -153,6 +173,8 @@ def generate_tasks(
         lo_rows.append((lat * s_l)[ok])
         po_rows.append((pw * s_p)[ok])
         got += int(ok.sum())
+    if got < n_tasks:
+        raise _too_sparse(model, n_tasks, got, m)
     return DSETask(
         net_idx=np.concatenate(net_rows)[:n_tasks],
         lat_obj=np.concatenate(lo_rows)[:n_tasks],

@@ -40,12 +40,13 @@ from repro.core.selector import set_select_route
 from repro.dataset.generator import DSETask, generate_dataset, generate_tasks
 from repro.design_models.dnnweaver import DnnWeaverModel
 from repro.design_models.im2col import Im2colModel
-from repro.design_models.tpu_mesh import TpuMeshModel
+from repro.design_models.tpu_mesh import DeepSeekV3Mesh, TpuMeshModel
 from repro.launch.compile_cache import use_compile_cache
 from repro.serve import DSEServer, ServeConfig
 from repro.serve.request import SOURCE_FAILED, DSEResponse
 
-MODELS = {m.name: m for m in (DnnWeaverModel, Im2colModel, TpuMeshModel)}
+MODELS = {m.name: m for m in (DnnWeaverModel, Im2colModel, TpuMeshModel,
+                              DeepSeekV3Mesh)}
 
 
 def main(argv=None) -> int:

@@ -33,11 +33,12 @@ from repro.core.explorer import ExplorerConfig
 from repro.dataset.generator import generate_dataset, generate_tasks
 from repro.design_models.dnnweaver import DnnWeaverModel
 from repro.design_models.im2col import Im2colModel
-from repro.design_models.tpu_mesh import TpuMeshModel
+from repro.design_models.tpu_mesh import DeepSeekV3Mesh, TpuMeshModel
 from repro.serve import (DSEServer, FrontendConfig, OnlineConfig, OnlineLoop,
                          ServeConfig, ServeFrontend, corrupt_checkpoint)
 
-MODELS = {m.name: m for m in (DnnWeaverModel, Im2colModel, TpuMeshModel)}
+MODELS = {m.name: m for m in (DnnWeaverModel, Im2colModel, TpuMeshModel,
+                              DeepSeekV3Mesh)}
 
 
 def main(argv=None) -> int:

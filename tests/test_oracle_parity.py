@@ -18,9 +18,10 @@ from repro.dataset.generator import generate_dataset
 from repro.design_models.base import DesignModel
 from repro.design_models.dnnweaver import DnnWeaverModel
 from repro.design_models.im2col import Im2colModel
-from repro.design_models.tpu_mesh import TpuMeshModel
+from repro.design_models.tpu_mesh import DeepSeekV3Mesh, TpuMeshModel
 
-MODELS = {m.name: m for m in (DnnWeaverModel, Im2colModel, TpuMeshModel)}
+MODELS = {m.name: m for m in (DnnWeaverModel, Im2colModel, TpuMeshModel,
+                              DeepSeekV3Mesh)}
 
 
 # ---------------------------------------------------------------------------

@@ -30,12 +30,15 @@ from repro.core.explorer import _cached_fwd
 from repro.core.fused_select import _fused_batch
 from repro.design_models.dnnweaver import DnnWeaverModel
 from repro.design_models.im2col import Im2colModel
+from repro.design_models.tpu_mesh import DeepSeekV3Mesh
 from repro.kernels import dispatch as D
 from repro.kernels import fused_mlp as FM
 
 from _hlo import loop_ops
 
 MODELS = {"dnnweaver": DnnWeaverModel, "im2col": Im2colModel}
+#: the served design models: the benchmark's, DeepSeek-V3's mesh preset too
+SERVED = {**MODELS, "tpu_mesh_dsv3": DeepSeekV3Mesh}
 HBM_BYTES = 16 * 2 ** 30          # one v5e chip
 
 
@@ -149,12 +152,12 @@ def test_fused_mlp_compiles_at_generator_shapes(one_chip, model_name, rows):
     _compile(FM.fused_mlp, *_spec(one_chip, (x, ws, bs)))
 
 
-@pytest.mark.parametrize("model_name", sorted(MODELS))
+@pytest.mark.parametrize("model_name", sorted(SERVED))
 def test_generator_forward_compiles_on_kernel_route(one_chip, kernel_route,
                                                     model_name):
     """The explorer's G forward (what serving dispatches) takes the
     megakernel once the dispatch rule sees a TPU."""
-    model = MODELS[model_name]()
+    model = SERVED[model_name]()
     cfg = _paper_cfg(model)
     # a fresh jit, not the process-wide cached one: this trace is routed
     # for a chip and must never serve a CPU call
@@ -226,14 +229,14 @@ def test_sharded_generator_forward_compiles_on_four_chips(four_chips,
              _spec(rows, keys))
 
 
-@pytest.mark.parametrize("model_name", sorted(MODELS))
+@pytest.mark.parametrize("model_name", sorted(SERVED))
 def test_fused_select_tile_loop_compiles_gather_and_divide_free(one_chip,
                                                                 model_name):
     """The serving select at the sweep's batch (T = 64, tile = 1024): its
     tile loop and replay branch hold no candidate-sized gather, and no
     integer division, which the TPU compiler would otherwise sink back
     into the loop from the once-per-call offset decode."""
-    model = MODELS[model_name]()
+    model = SERVED[model_name]()
     t, tile = 64, 1024
     f32, i32 = jnp.float32, jnp.int32
     args = _spec(one_chip, (
