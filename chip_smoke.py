@@ -61,7 +61,7 @@ from repro.core import gan as G  # noqa: E402
 from repro.core import shard  # noqa: E402
 from repro.core import train as T  # noqa: E402
 from repro.core.dse_api import GANDSE  # noqa: E402
-from repro.core.explorer import task_keys  # noqa: E402
+from repro.core.explorer import task_seeds  # noqa: E402
 from repro.dataset.generator import generate_dataset, generate_tasks  # noqa: E402
 from repro.design_models.dnnweaver import DnnWeaverModel  # noqa: E402
 from repro.design_models.im2col import Im2colModel  # noqa: E402
@@ -297,7 +297,7 @@ def serve_engine(engine, seed: int) -> None:
     ds = engine.ds
     net = ds.net_encoded(model, tasks.net_idx[:t])
     obj = ds.obj_encoded(tasks.lat_obj[:t], tasks.pow_obj[:t])
-    kernel_text(fwd, engine.g_params, net, obj, task_keys(seed, t),
+    kernel_text(fwd, engine.g_params, net, obj, task_seeds(seed, t),
                 n_samples=engine.explorer_cfg.noise_samples)
     print(f"[smoke] serve:{model.name} compiled G forward holds the "
           f"megakernel", flush=True)

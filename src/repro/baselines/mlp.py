@@ -189,8 +189,8 @@ class LargeMLP:
         obj_enc = self.ds.obj_encoded(np.atleast_1d(lat_obj),
                                       np.atleast_1d(pow_obj))
         keys = task_keys(seed, net_enc.shape[0])
-        # task-sharded over the active mesh (no-op without one): put_sharded
-        # is a drop-in for jnp.asarray, see repro.core.shard
+        # task-sharded over the active mesh (passed through without one),
+        # see repro.core.shard.put_sharded
         fwd_mean = _cached_fwd(self.model.space, self.noise_dim,
                                self.use_fused, mesh=shard.get_task_mesh())[1]
         return fwd_mean(self.params, shard.put_sharded(net_enc),

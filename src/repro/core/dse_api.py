@@ -21,7 +21,7 @@ from repro.core import gan as G
 from repro.core import shard
 from repro.core.explorer import Explorer, ExplorerConfig, row_seeds  # noqa: F401
 # (row_seeds re-exported: the per-row seed convention lives next to
-# task_keys so the device and host routes cannot drift apart)
+# task_seeds so the device and host routes cannot drift apart)
 from repro.core.fused_select import fused_select_batch
 from repro.core.selector import Selection, select, select_batch
 from repro.core.train import TrainState, train_gan

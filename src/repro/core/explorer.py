@@ -77,9 +77,9 @@ def row_seeds(seed, n: int) -> np.ndarray:
     return seeds
 
 
-def task_keys(seed, n: int) -> jnp.ndarray:
-    """Per-task noise keys: PRNGKey over `row_seeds(seed, n)`, masked in
-    host int64.
+def task_seeds(seed, n: int) -> np.ndarray:
+    """Per-task noise seeds: `row_seeds(seed, n)` masked to its low 32 bits
+    in host int64, as uint32 — what PRNGKey turns into task t's key.
 
     The sum must not happen in device int32: Python-int seeds >= 2**31 raise
     OverflowError at dispatch, and in-range seeds whose sum crosses 2**31
@@ -89,8 +89,13 @@ def task_keys(seed, n: int) -> jnp.ndarray:
     (including negatives), while keeping any int64 seed valid and collision
     -free within a batch.
     """
-    seeds = row_seeds(seed, n) & np.int64(0xFFFFFFFF)
-    return jax.vmap(jax.random.PRNGKey)(seeds.astype(np.uint32))
+    return (row_seeds(seed, n) & np.int64(0xFFFFFFFF)).astype(np.uint32)
+
+
+def task_keys(seed, n: int) -> jnp.ndarray:
+    """Per-task noise keys: PRNGKey over `task_seeds(seed, n)`, eagerly.
+    The explorer's forward derives the same keys inside its program."""
+    return jax.vmap(jax.random.PRNGKey)(task_seeds(seed, n))
 
 
 def _employed_choices(probs_g: np.ndarray, thresh: float) -> List[np.ndarray]:
@@ -299,8 +304,8 @@ def enumerate_candidates_batch(
         "dense route needs max group size <= 1024 and cap <= 2**20 " \
         "(use the fused tiled route for larger caps)"
     masks, unravel = _batched_enum_fns(space)
-    keep, counts, total = masks(shard.put_sharded(probs), jnp.float32(thresh),
-                                jnp.int32(max_candidates))
+    keep, counts, total = masks(shard.put_sharded(probs), np.float32(thresh),
+                                np.int32(max_candidates))
     counts_host = np.asarray(total)
     c_pad = pow2_bucket(int(counts_host.max(initial=1)))
     cand, valid = unravel(keep, counts, total, c_pad)
@@ -325,6 +330,33 @@ def flatten_task_draws(net_enc, obj_enc, keys, n_samples: int, noise_fn):
     return rep(net_enc), rep(obj_enc), noise.reshape(t * n_samples, -1)
 
 
+def _task_probs(space: ConfigSpace, gan_cfg: G.GANConfig, chained: bool,
+                mesh, g_params, net_enc, obj_enc, keys, n_samples: int):
+    """Traceable body of the explorer's G forward: (T, onehot_width) mean
+    probs of each task's n_samples draws, from its (T,) noise keys."""
+    if chained:
+        def noise_fn(key, s):
+            return G.sample_noise(jax.random.fold_in(key, s), 1, gan_cfg)[0]
+
+        t = net_enc.shape[0]
+        net_r, obj_r, noise_r = flatten_task_draws(
+            net_enc, obj_enc, keys, n_samples, noise_fn)
+        probs = G.generator_apply(
+            g_params, space, net_r, obj_r, noise_r,
+            use_fused=gan_cfg.use_fused, chained=True, mesh=mesh)
+        return jnp.mean(probs.reshape(t, n_samples, -1), axis=1)
+
+    def one_task(net, obj, key):
+        def one(s):
+            noise = G.sample_noise(jax.random.fold_in(key, s), 1, gan_cfg)
+            return G.generator_apply(g_params, space, net[None], obj[None],
+                                     noise, use_fused=gan_cfg.use_fused,
+                                     mesh=mesh)[0]
+        return jnp.mean(jax.vmap(one)(jnp.arange(n_samples)), axis=0)
+
+    return jax.vmap(one_task)(net_enc, obj_enc, keys)
+
+
 @functools.lru_cache(maxsize=None)
 def _cached_fwd(space: ConfigSpace, gan_cfg: G.GANConfig,
                 chained: bool = None, mesh=None):
@@ -334,9 +366,12 @@ def _cached_fwd(space: ConfigSpace, gan_cfg: G.GANConfig,
     mesh the forward's inputs are sharded over (None = one device); the
     kernel route runs per shard on it (kernels/dispatch.py).
 
-    Per-task noise streams: task t averages n_samples draws from
-    fold_in(keys[t], s) — the same streams whether tasks run one at a time
-    or batched, which is the batched-vs-sequential parity contract.
+    The forward takes the (T,) uint32 `task_seeds` and derives each task's
+    PRNGKey inside the program (bitwise the keys `task_keys` builds), so a
+    warm call runs no eager JAX on the host.  Per-task noise streams: task
+    t averages n_samples draws from fold_in(key[t], s) — the same streams
+    whether tasks run one at a time or batched, which is the
+    batched-vs-sequential parity contract.
 
     ``chained`` (None = dispatch auto, i.e. TPU) flattens the (T, samples)
     draws into one row batch and runs G through the layer-chained Pallas
@@ -348,29 +383,11 @@ def _cached_fwd(space: ConfigSpace, gan_cfg: G.GANConfig,
     if chained is None:
         chained = D.fused_enabled(gan_cfg.use_fused) and D.on_tpu()
 
-    def noise_fn(key, s):
-        return G.sample_noise(jax.random.fold_in(key, s), 1, gan_cfg)[0]
-
     @functools.partial(jax.jit, static_argnames="n_samples")
-    def fwd(g_params, net_enc, obj_enc, keys, n_samples):
-        if chained:
-            t = net_enc.shape[0]
-            net_r, obj_r, noise_r = flatten_task_draws(
-                net_enc, obj_enc, keys, n_samples, noise_fn)
-            probs = G.generator_apply(
-                g_params, space, net_r, obj_r, noise_r,
-                use_fused=gan_cfg.use_fused, chained=True, mesh=mesh)
-            return jnp.mean(probs.reshape(t, n_samples, -1), axis=1)
-
-        def one_task(net, obj, key):
-            def one(s):
-                noise = G.sample_noise(jax.random.fold_in(key, s), 1, gan_cfg)
-                return G.generator_apply(g_params, space, net[None], obj[None],
-                                         noise, use_fused=gan_cfg.use_fused,
-                                         mesh=mesh)[0]
-            return jnp.mean(jax.vmap(one)(jnp.arange(n_samples)), axis=0)
-
-        return jax.vmap(one_task)(net_enc, obj_enc, keys)
+    def fwd(g_params, net_enc, obj_enc, seeds, n_samples):
+        keys = jax.vmap(jax.random.PRNGKey)(seeds)
+        return _task_probs(space, gan_cfg, chained, mesh, g_params, net_enc,
+                           obj_enc, keys, n_samples)
 
     return fwd
 
@@ -399,19 +416,21 @@ class Explorer:
         (seed[t]) when ``seed`` is a per-task array — so row t is
         bitwise-equal to a single-task call with that seed: batching a task
         never changes its candidates.  The sum runs in host int64 (see
-        `task_keys`) so large seeds neither raise nor alias.
+        `task_seeds`) so large seeds neither raise nor alias.
 
-        When a task mesh is active (``shard.set_task_mesh``) and the task
-        count divides its shard count, the inputs land task-sharded over
-        the mesh and the same jitted forward runs SPMD across devices —
-        lane numerics (and thus candidates) are unchanged.
+        The inputs stay numpy: the jitted forward transfers them itself
+        and derives the keys on device.  When a task mesh is active
+        (``shard.set_task_mesh``) and the task count divides its shard
+        count, the inputs land task-sharded over the mesh and the same
+        jitted forward runs SPMD across devices — lane numerics (and thus
+        candidates) are unchanged.
         """
         net_enc = self.ds.net_encoded(self.model, np.atleast_2d(net_idx))
         obj_enc = self.ds.obj_encoded(np.atleast_1d(lat_obj),
                                       np.atleast_1d(pow_obj))
-        keys = task_keys(seed, net_enc.shape[0])
+        seeds = task_seeds(seed, net_enc.shape[0])
         return self._fwd(self.g_params, shard.put_sharded(net_enc),
-                         shard.put_sharded(obj_enc), shard.put_sharded(keys),
+                         shard.put_sharded(obj_enc), shard.put_sharded(seeds),
                          n_samples=self.cfg.noise_samples)
 
     def generator_probs(self, net_idx: np.ndarray, lat_obj, pow_obj,

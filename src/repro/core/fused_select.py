@@ -328,8 +328,8 @@ def fused_select_batch(
         lo = np.asarray(lat_obj, np.float64).reshape(-1)
         po = np.asarray(pow_obj, np.float64).reshape(-1)
         out = run(
-            shard.put_sharded(probs), jnp.float32(thresh),
-            jnp.int32(max_candidates), shard.put_sharded(net_idx),
+            shard.put_sharded(probs), np.float32(thresh),
+            np.int32(max_candidates), shard.put_sharded(net_idx),
             shard.put_sharded(lo.astype(np.float32)),
             shard.put_sharded(po.astype(np.float32)),
         )

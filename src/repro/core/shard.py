@@ -137,19 +137,17 @@ def pad_tasks(tasks, seeds: np.ndarray, mesh: Optional[Mesh] = None):
 def put_sharded(x, mesh: Optional[Mesh] = None, axis: int = 0):
     """Place `x` with its `axis` dim sharded over the mesh's task axes.
 
-    Falls back to ``jnp.asarray`` (default single-device placement) when no
-    mesh is active, the mesh has no task axes, or the dim does not divide
-    the shard count — the exact pre-sharding behavior, so every call site
-    is a drop-in replacement for ``jnp.asarray``.
+    Hands `x` back as it is (numpy stays numpy) when no mesh is active, the
+    mesh has no task axes, or the dim does not divide the shard count: the
+    jitted program it feeds then transfers it to the default device in its
+    own C++ dispatch, with no eager transfer from Python.
     """
-    import jax.numpy as jnp
-
     mesh = get_task_mesh() if mesh is None else mesh
     axes = task_axes(mesh)
     ndim = np.ndim(x)
     if (axes is None or ndim <= axis
             or np.shape(x)[axis] % axis_size(mesh, axes) != 0):
-        return jnp.asarray(x)
+        return x
     spec = [None] * ndim
     spec[axis] = axes
     return jax.device_put(x, NamedSharding(mesh, P(*spec)))

@@ -10,7 +10,7 @@ of m requests is padded to the next power of two by repeating its last row
 <= log2(max_batch) batch-size entries no matter how ragged the arrival
 pattern is.
 
-Per-request seeds ride along as a (T,) array (`task_keys` array form), so
+Per-request seeds ride along as a (T,) array (`row_seeds` array form), so
 a request's Selection never depends on which micro-batch it landed in or
 at which position.
 

@@ -207,6 +207,56 @@ def test_task_keys_survive_large_seeds(models, tiny_gan_cfg, small_dataset):
                                 r.selection)
 
 
+@pytest.mark.parametrize("seed", [2**31 + 5,
+                                  np.array([-7, 2**40, 3, 9, 2**33, 1])],
+                         ids=["scalar", "per-row"])
+def test_warm_explore_batch_runs_no_eager_jax(models, tiny_gan_cfg,
+                                              small_dataset, monkeypatch,
+                                              seed):
+    """A warm engine call is numpy preparation plus the two jitted
+    programs: no eager `task_keys`, no Python-level transfer in
+    `put_sharded` without a mesh, and no new entry in either program's
+    cache for new seeds.  Its Selections still equal the sequential
+    route's."""
+    from repro.core import explorer as E
+    from repro.core import shard
+
+    model = models["dnnweaver"]
+    g = _attached(model, tiny_gan_cfg, small_dataset)
+    tasks = generate_tasks(model, 6, seed=4)
+    g.explore_batch(generate_tasks(model, 8, seed=11), seed=101)
+    fwd = g._explorer._fwd
+    run = model.__dict__["_fused_select"][g.explorer_cfg.select_tile]
+    sizes = fwd._cache_size(), run._cache_size()
+
+    def refuse(*a, **k):
+        raise AssertionError("warm engine call built keys eagerly")
+
+    calls = []
+
+    def counted(fn):
+        def spy(*a, **k):
+            calls.append(fn.__name__)
+            return fn(*a, **k)
+        return spy
+
+    assert shard.get_task_mesh() is None
+    monkeypatch.setattr(E, "task_keys", refuse)
+    monkeypatch.setattr(jnp, "asarray", counted(jnp.asarray))
+    monkeypatch.setattr(jax, "device_put", counted(jax.device_put))
+    batched = g.explore_batch(tasks, seed=seed)
+    assert calls == []
+    assert (fwd._cache_size(), run._cache_size()) == sizes
+    monkeypatch.undo()
+
+    seeds = E.row_seeds(seed, 6)
+    for i in range(6):
+        r = g.explore(tasks.net_idx[i], tasks.lat_obj[i], tasks.pow_obj[i],
+                      seed=seeds[i])
+        _assert_selection_equal("no_eager", i, batched[i].selection,
+                                r.selection)
+
+
 @pytest.mark.parametrize("name", sorted(MODELS))
 def test_oracle_broadcasts_task_by_candidate_grids(name, models):
     """(T, 1, n_net) x (T, C, n_cfg) -> (T, C): one grid call equals the

@@ -17,7 +17,9 @@ Caution for new tests: ``_cached_fwd`` memoizes jitted forwards on
 interpret-routed for that key, so interpret-mode traces here always use a
 config with ``use_fused=True`` (a key the non-interpret tests never use).
 """
+import contextlib
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -27,7 +29,8 @@ import pytest
 from repro.core import gan as G
 from repro.core import train as T
 from repro.core.dse_api import GANDSE
-from repro.core.explorer import _cached_fwd, task_keys
+from repro.core.explorer import (_cached_fwd, _task_probs, task_keys,
+                                 task_seeds)
 from repro.dataset.generator import generate_dataset
 from repro.design_models.dnnweaver import DnnWeaverModel
 from repro.kernels import dispatch as D
@@ -144,21 +147,46 @@ def test_explorer_chained_route_parity(setup):
     model, cfg, ds, gp, dp, batch, rng = setup
     net_enc = jnp.asarray(ds.net_encoded(model, ds.net_idx[:5]))
     obj_enc = jnp.asarray(ds.obj_encoded(ds.latency[:5], ds.power[:5]))
-    keys = task_keys(7, 5)
+    seeds = task_seeds(7, 5)
 
     p_vmap = _cached_fwd(model.space, cfg, chained=False)(
-        gp, net_enc, obj_enc, keys, n_samples=3)
+        gp, net_enc, obj_enc, seeds, n_samples=3)
     p_chain = _cached_fwd(model.space, cfg, chained=True)(
-        gp, net_enc, obj_enc, keys, n_samples=3)
+        gp, net_enc, obj_enc, seeds, n_samples=3)
     np.testing.assert_allclose(np.asarray(p_vmap), np.asarray(p_chain),
                                rtol=1e-5, atol=1e-6)
 
     fused_cfg = dataclasses.replace(cfg, use_fused=True)
     with D.force_interpret():
         p_kernel = _cached_fwd(model.space, fused_cfg, chained=True)(
-            gp, net_enc, obj_enc, keys, n_samples=3)
+            gp, net_enc, obj_enc, seeds, n_samples=3)
     np.testing.assert_allclose(np.asarray(p_vmap), np.asarray(p_kernel),
                                rtol=1e-4, atol=1e-5)
+
+
+@pytest.mark.parametrize("route", ["vmap", "chained-interpret"])
+@pytest.mark.parametrize("seed", [
+    7, -5, 2**31 + 3, 2**40 + 11,
+    np.array([-3, 2**33, 5, 2**31 - 1, -2**40], np.int64)],
+    ids=["7", "negative", "2^31+3", "2^40+11", "per-row"])
+def test_forward_keys_in_program_match_task_keys(setup, route, seed):
+    """The forward derives each task's key from its uint32 seed inside the
+    program; its probs equal, bit for bit, the same forward body fed the
+    host-built `task_keys`, for every seed form `task_keys` accepts."""
+    model, cfg, ds, gp, dp, batch, rng = setup
+    net_enc = ds.net_encoded(model, ds.net_idx[:5])
+    obj_enc = ds.obj_encoded(ds.latency[:5], ds.power[:5])
+    chained = route == "chained-interpret"
+    if chained:
+        cfg = dataclasses.replace(cfg, use_fused=True)
+    with D.force_interpret() if chained else contextlib.nullcontext():
+        new = _cached_fwd(model.space, cfg, chained=chained)(
+            gp, net_enc, obj_enc, task_seeds(seed, 5), n_samples=3)
+        old = jax.jit(functools.partial(_task_probs, model.space, cfg,
+                                        chained, None),
+                      static_argnames="n_samples")(
+            gp, net_enc, obj_enc, task_keys(seed, 5), n_samples=3)
+    np.testing.assert_array_equal(np.asarray(new), np.asarray(old))
 
 
 def test_large_mlp_chained_route_parity(rng):

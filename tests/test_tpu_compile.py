@@ -164,13 +164,11 @@ def test_generator_forward_compiles_on_kernel_route(one_chip, kernel_route,
     fwd = _cached_fwd.__wrapped__(model.space, cfg)
     params, _, _ = _gan_shapes(model, cfg)
     t = 64
-    keys = jax.eval_shape(lambda: jax.vmap(jax.random.PRNGKey)(
-        jnp.arange(t, dtype=jnp.uint32)))
     args = _spec(one_chip, (
         params,
         jax.ShapeDtypeStruct((t, cfg.n_net), jnp.float32),
         jax.ShapeDtypeStruct((t, cfg.n_obj), jnp.float32),
-        keys))
+        jax.ShapeDtypeStruct((t,), jnp.uint32)))
     _compile(lambda *a: fwd(*a, n_samples=1), *args)
 
 
@@ -220,13 +218,11 @@ def test_sharded_generator_forward_compiles_on_four_chips(four_chips,
     gp, _, _ = _gan_shapes(model, cfg)
     t = 64
     rows = NamedSharding(four_chips, P("data"))
-    keys = jax.eval_shape(lambda: jax.vmap(jax.random.PRNGKey)(
-        jnp.arange(t, dtype=jnp.uint32)))
     _compile(lambda *a: fwd(*a, n_samples=1),
              _spec(NamedSharding(four_chips, P()), gp),
              jax.ShapeDtypeStruct((t, cfg.n_net), jnp.float32, sharding=rows),
              jax.ShapeDtypeStruct((t, cfg.n_obj), jnp.float32, sharding=rows),
-             _spec(rows, keys))
+             jax.ShapeDtypeStruct((t,), jnp.uint32, sharding=rows))
 
 
 @pytest.mark.parametrize("model_name", sorted(SERVED))

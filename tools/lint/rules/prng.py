@@ -10,7 +10,7 @@ GL102 seed-int32-overflow: host-side Python-int arithmetic fed straight
 into ``PRNGKey`` can silently wrap int32 for large seeds/offsets (the
 PR-3 bug).  The sanctioned forms are ``jax.random.fold_in(key, i)`` or
 masking the int64 sum with ``& 0xFFFFFFFF`` before key construction
-(`core/explorer.py` ``task_keys``).
+(`core/explorer.py` ``task_seeds``).
 """
 from __future__ import annotations
 
