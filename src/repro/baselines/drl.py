@@ -20,9 +20,9 @@ DSE-time rollouts have two routes:
   T tasks instead of (rollout_len x T) host oracle calls.  Lane t draws
   from PRNGKey(seed + t), so a batched lane is bitwise-equal to the
   single-task device run with seed + t; winners are re-scored once by the
-  float64 host oracle (the ``select_batch`` rule).
-- **host** (fallback for models without a jnp oracle): the original numpy
-  loop.
+  float64 host oracle (the fused select's host-tail rule).
+- **host** (fallback for models without a jnp oracle, and the sequential
+  ``explore_tasks(batched=False)`` loop): the original numpy loop.
 """
 from __future__ import annotations
 
@@ -323,11 +323,10 @@ class PolicyGradientDRL:
 
     # --- public API ---------------------------------------------------------
     def explore(self, net_idx: np.ndarray, lat_obj: float, pow_obj: float,
-                seed: int = 0, use_jax: Optional[bool] = None) -> DSEResult:
-        # a model without a jnp oracle always takes the host route, even
-        # when the device route is requested (the GANDSE fallback rule)
-        use_jax = self.model.has_jax_oracle and (use_jax is None or use_jax)
-        if use_jax:
+                seed: int = 0) -> DSEResult:
+        # a model without a jnp oracle takes the host route (the GANDSE
+        # fallback rule)
+        if self.model.has_jax_oracle:
             tasks = DSETask.single(net_idx, lat_obj, pow_obj)
             return self._explore_device(tasks, seed)[0]
         return self._explore_host(net_idx, lat_obj, pow_obj, seed)
@@ -341,7 +340,6 @@ class PolicyGradientDRL:
         if batched:
             return self._explore_device(tasks, seed)
         seeds = row_seeds(seed, n_tasks)
-        return [self.explore(tasks.net_idx[i], tasks.lat_obj[i],
-                             tasks.pow_obj[i], seed=int(seeds[i]),
-                             use_jax=False)
+        return [self._explore_host(tasks.net_idx[i], tasks.lat_obj[i],
+                                   tasks.pow_obj[i], int(seeds[i]))
                 for i in range(n_tasks)]

@@ -9,9 +9,9 @@ construction ("much larger than the G in the GAN").  The design selector
 Exploration mirrors the GANDSE explorer exactly: the MLP receives the same
 noise input as G (§7.1.4), task t averages ``noise_samples`` forward passes
 drawn from PRNGKey(seed + t), and ``explore_tasks`` serves the whole batch
-device-resident (vmapped forward -> on-device candidate enumeration ->
-batched Algorithm 2), falling back to the sequential host loop for models
-without a jnp oracle.
+device-resident (vmapped forward -> the fused streaming select of
+``core/fused_select``), falling back to the sequential host loop for
+models without a jnp oracle.
 """
 from __future__ import annotations
 
@@ -27,10 +27,9 @@ import numpy as np
 from repro.core import gan as G
 from repro.core import shard
 from repro.core.explorer import (ExplorerConfig, enumerate_candidates,
-                                 enumerate_candidates_batch,
                                  flatten_task_draws, task_keys)
 from repro.core.fused_select import fused_select_batch
-from repro.core.selector import select, select_batch
+from repro.core.selector import select
 from repro.core.dse_api import DSEResult, row_seeds
 from repro.core.train import encode_batch
 from repro.dataset.generator import Dataset, DSETask, generate_dataset
@@ -118,14 +117,6 @@ class LargeMLP:
         self._fwd = _cached_fwd(self.model.space, self.noise_dim,
                                 self.use_fused)[0]
 
-    def set_use_fused(self, use_fused: Optional[bool]) -> "LargeMLP":
-        """Flip the fused-MLP dispatch (serving-layer override hook);
-        refreshes the cached jitted forwards for the new route."""
-        self.use_fused = use_fused
-        self._fwd = _cached_fwd(self.model.space, self.noise_dim,
-                                use_fused)[0]
-        return self
-
     def n_params(self) -> int:
         return sum(int(np.prod(p.shape)) for p in jax.tree.leaves(self.params))
 
@@ -212,9 +203,8 @@ class LargeMLP:
     def explore_batch(self, tasks: DSETask, seed: int = 0) -> List[DSEResult]:
         """Batched device-resident exploration, same structure (and parity
         contract) as ``GANDSE.explore_batch``: vmapped forward -> fused
-        streaming enumerate/score/select (``batch_route="dense"`` on the
-        explorer config keeps the reference materialized route).
-        dse_seconds is the amortized per-task wall-clock."""
+        streaming enumerate/score/select.  dse_seconds is the amortized
+        per-task wall-clock."""
         n_tasks = int(tasks.net_idx.shape[0])
         if n_tasks == 0:
             return []
@@ -227,19 +217,12 @@ class LargeMLP:
         tasks_p, seeds, n_real = shard.pad_tasks(tasks, seeds)
         probs = self.generator_probs_device(tasks_p.net_idx, tasks_p.lat_obj,
                                             tasks_p.pow_obj, seeds)
-        if self.explorer_cfg.batch_route == "dense":
-            cand, valid, counts = enumerate_candidates_batch(
-                self.model.space, probs, self.explorer_cfg.prob_threshold,
-                self.explorer_cfg.max_candidates)
-            sels = select_batch(self.model, tasks_p.net_idx, cand, valid,
-                                counts, tasks_p.lat_obj, tasks_p.pow_obj)
-        else:
-            sels = fused_select_batch(
-                self.model, tasks_p.net_idx, probs,
-                self.explorer_cfg.prob_threshold,
-                self.explorer_cfg.max_candidates,
-                tasks_p.lat_obj, tasks_p.pow_obj,
-                tile=self.explorer_cfg.select_tile)
+        sels = fused_select_batch(
+            self.model, tasks_p.net_idx, probs,
+            self.explorer_cfg.prob_threshold,
+            self.explorer_cfg.max_candidates,
+            tasks_p.lat_obj, tasks_p.pow_obj,
+            tile=self.explorer_cfg.select_tile)
         per_task = (time.time() - t0) / n_real
         return [
             DSEResult(sel, float(tasks.lat_obj[i]), float(tasks.pow_obj[i]),

@@ -4,10 +4,9 @@ Uniformly samples N configurations and applies the Algorithm 2 selector.
 Useful as the weakest-reasonable baseline and in property tests (any
 learned method should beat it at equal evaluation budget).
 
-``explore_tasks`` serves a task batch device-resident: candidate sampling
-stays on host (cheap, and bitwise-identical to the per-task route), the T
-Algorithm 2 update chains run as one vmapped scan (``select_batch``).
-Models without a jnp oracle fall back to the sequential host loop.
+``explore_tasks`` runs the tasks one after another through ``explore``:
+task t samples from ``default_rng(seed + t)`` (or ``seed[t]``), and
+``select`` picks its route from the candidate count.
 """
 from __future__ import annotations
 
@@ -17,7 +16,7 @@ from typing import List, Optional
 
 import numpy as np
 
-from repro.core.selector import select, select_batch
+from repro.core.selector import select
 from repro.core.dse_api import DSEResult, row_seeds
 from repro.dataset.generator import Dataset, DSETask
 from repro.design_models.base import DesignModel
@@ -49,29 +48,9 @@ class RandomSearch:
 
     def explore_tasks(self, tasks: DSETask, seed: int = 0,
                       batched: Optional[bool] = None) -> List[DSEResult]:
-        # models without a jnp oracle always take the host route (the
-        # GANDSE fallback rule), even when the batched route is requested
-        batched = self.model.has_jax_oracle and (batched is None or batched)
-        n_tasks = int(tasks.net_idx.shape[0])
-        if n_tasks == 0:
-            return []
-        seeds = row_seeds(seed, n_tasks)
-        if not batched:
-            return [self.explore(tasks.net_idx[i], tasks.lat_obj[i],
-                                 tasks.pow_obj[i], seed=int(seeds[i]))
-                    for i in range(n_tasks)]
-        t0 = time.time()
-        # task t samples from default_rng(seeds[t]): same candidate sets as
-        # the sequential route, whatever the batch composition
-        cand = np.stack([self._candidates(int(seeds[t]))
-                         for t in range(n_tasks)])
-        valid = np.ones(cand.shape[:2], bool)
-        counts = np.full(n_tasks, self.n_samples)
-        sels = select_batch(self.model, tasks.net_idx, cand, valid, counts,
-                            tasks.lat_obj, tasks.pow_obj)
-        per_task = (time.time() - t0) / n_tasks
-        return [
-            DSEResult(sel, float(tasks.lat_obj[i]), float(tasks.pow_obj[i]),
-                      per_task)
-            for i, sel in enumerate(sels)
-        ]
+        """The per-task route for every batch: ``batched`` is accepted for
+        the DSEMethod protocol and changes nothing."""
+        seeds = row_seeds(seed, int(tasks.net_idx.shape[0]))
+        return [self.explore(tasks.net_idx[i], tasks.lat_obj[i],
+                             tasks.pow_obj[i], seed=int(seeds[i]))
+                for i in range(len(seeds))]

@@ -14,12 +14,13 @@ Two routes share the annealing schedule:
   config.  Tasks whose best violation hits zero freeze (the batched while
   keeps them fixed), matching the sequential early exit, so lane t is
   bitwise-equal to a single-task device run with seed + t.
-- **host** (fallback for models without a jnp oracle, or ``use_jax=False``):
-  the original numpy loop with one ``evaluate_indices`` call per step.
+- **host** (fallback for models without a jnp oracle, and the sequential
+  ``explore_tasks(batched=False)`` loop): the original numpy loop with
+  one ``evaluate_indices`` call per step.
 
 Winners from the device route are re-scored once with the float64 host
 oracle so reported metrics stay precision-consistent with the host route
-(the same rule as ``select_batch``).
+(the same rule as the fused select's host tail).
 """
 from __future__ import annotations
 
@@ -233,12 +234,10 @@ class SimulatedAnnealing:
 
     # --- public API ---------------------------------------------------------
     def explore(self, net_idx: np.ndarray, lat_obj: float, pow_obj: float,
-                seed: Optional[int] = None,
-                use_jax: Optional[bool] = None) -> DSEResult:
-        # a model without a jnp oracle always takes the host route, even
-        # when the device route is requested (the GANDSE fallback rule)
-        use_jax = self.model.has_jax_oracle and (use_jax is None or use_jax)
-        if use_jax:
+                seed: Optional[int] = None) -> DSEResult:
+        # a model without a jnp oracle takes the host route (the GANDSE
+        # fallback rule)
+        if self.model.has_jax_oracle:
             tasks = DSETask.single(net_idx, lat_obj, pow_obj)
             return self._explore_device(
                 tasks, self.seed if seed is None else seed)[0]
@@ -253,7 +252,6 @@ class SimulatedAnnealing:
         if batched:
             return self._explore_device(tasks, seed)
         seeds = row_seeds(seed, n_tasks)
-        return [self.explore(tasks.net_idx[i], tasks.lat_obj[i],
-                             tasks.pow_obj[i], seed=int(seeds[i]),
-                             use_jax=False)
+        return [self._explore_host(tasks.net_idx[i], tasks.lat_obj[i],
+                                   tasks.pow_obj[i], int(seeds[i]))
                 for i in range(n_tasks)]

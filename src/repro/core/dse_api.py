@@ -23,7 +23,7 @@ from repro.core.explorer import Explorer, ExplorerConfig, row_seeds  # noqa: F40
 # (row_seeds re-exported: the per-row seed convention lives next to
 # task_seeds so the device and host routes cannot drift apart)
 from repro.core.fused_select import fused_select_batch
-from repro.core.selector import Selection, select, select_batch
+from repro.core.selector import Selection, select
 from repro.core.train import TrainState, train_gan
 from repro.dataset.generator import Dataset, DSETask, generate_dataset
 from repro.design_models.base import DesignModel
@@ -145,17 +145,6 @@ class GANDSE:
         self.attach(self.ds, self.state.g_params)
         return self.state
 
-    def set_use_fused(self, use_fused: Optional[bool]) -> "GANDSE":
-        """Flip the Pallas fused-MLP dispatch (None = backend auto) — the
-        serving-layer override hook.  Rebuilds the explorer when one is
-        attached: the compiled forward is cached on (space, gan_cfg), so
-        flipping back to a previously used setting never recompiles."""
-        self.gan_cfg = dataclasses.replace(self.gan_cfg, use_fused=use_fused)
-        if self._explorer is not None:
-            assert self.ds is not None    # an attached explorer implies it
-            self.attach(self.ds, self._explorer.g_params)
-        return self
-
     @property
     def g_params(self) -> Optional[Dict]:
         """Currently attached generator params (None before
@@ -190,11 +179,8 @@ class GANDSE:
         fused streaming enumerate/score/select (``core/fused_select``) —
         one uninterrupted device program for the whole task batch, with
         zero mid-dispatch host syncs and candidate caps up to 2**26.
-        ``ExplorerConfig.batch_route="dense"`` keeps the reference route
-        (materialized candidate tensor + vmapped scan, caps to 2**20);
-        Selections are bit-identical either way.  Task i returns the same
-        Selection
-        as ``explore(tasks.net_idx[i], ..., seed=seed + i)`` — or
+        Task i returns the same Selection as
+        ``explore(tasks.net_idx[i], ..., seed=seed + i)`` — or
         ``seed=seed[i]`` when ``seed`` is a (T,) per-task array — identical
         candidate sets always; the winner too, except when `explore` routes
         a small candidate set through the float64 host loop and two
@@ -221,23 +207,16 @@ class GANDSE:
         t0 = time.time()
         seeds = row_seeds(seed, n_tasks)
         tasks_p, seeds, n_real = shard.pad_tasks(tasks, seeds)
-        if self.explorer_cfg.batch_route == "dense":
-            # reference route: materialized candidate tensor + vmapped scan
-            cand, valid, counts = self._explorer.candidates_batch(
-                tasks_p.net_idx, tasks_p.lat_obj, tasks_p.pow_obj, seed=seeds)
-            sels = select_batch(self.model, tasks_p.net_idx, cand, valid,
-                                counts, tasks_p.lat_obj, tasks_p.pow_obj)
-        else:
-            with trace.span("dse.gfwd"):
-                probs = self._explorer.generator_probs_device(
-                    tasks_p.net_idx, tasks_p.lat_obj, tasks_p.pow_obj,
-                    seed=seeds)
-            sels = fused_select_batch(
-                self.model, tasks_p.net_idx, probs,
-                self.explorer_cfg.prob_threshold,
-                self.explorer_cfg.max_candidates,
-                tasks_p.lat_obj, tasks_p.pow_obj,
-                tile=self.explorer_cfg.select_tile, stats=self.stats)
+        with trace.span("dse.gfwd"):
+            probs = self._explorer.generator_probs_device(
+                tasks_p.net_idx, tasks_p.lat_obj, tasks_p.pow_obj,
+                seed=seeds)
+        sels = fused_select_batch(
+            self.model, tasks_p.net_idx, probs,
+            self.explorer_cfg.prob_threshold,
+            self.explorer_cfg.max_candidates,
+            tasks_p.lat_obj, tasks_p.pow_obj,
+            tile=self.explorer_cfg.select_tile, stats=self.stats)
         per_task = (time.time() - t0) / n_real
         return [
             DSEResult(sel, float(tasks.lat_obj[i]), float(tasks.pow_obj[i]),

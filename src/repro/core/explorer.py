@@ -12,26 +12,22 @@ for realistic thresholds while bounding memory.
 
 Two routes produce identical candidate sets:
 
-- ``enumerate_candidates``: host numpy + ``itertools.product`` for one task;
-- ``enumerate_candidates_batch``: the device-resident batch twin — threshold
-  mask -> per-group employed counts -> mixed-radix index arithmetic that
-  unravels the cartesian product directly into a ``(T, C_pad, n_dims)``
-  padded candidate tensor, with ``C_pad`` bucketed to the next power of two
-  so the jit cache stays bounded.
-
-A third route consumes the same enumeration *without* the dense tensor:
-``core/fused_select`` applies the identical mixed-radix arithmetic to
-tile-sized index windows inside one fused enumerate->score->select
-program, which is how caps beyond the dense materialization bound
-(``_DENSE_LIM``) up to ``_PROD_LIM = 2**26`` are reached.  Both routes
-share the traceable cores in ``_enum_core`` so they cannot drift.
+- ``enumerate_candidates``: host numpy + ``itertools.product`` for one
+  task — the reference, and the sequential route's enumeration;
+- ``core/fused_select``: the batched device route.  The traceable cores in
+  ``_enum_core`` apply the same threshold/argmax/trim rules on device
+  (threshold mask -> per-group employed counts -> mixed-radix strides),
+  and the fused program unravels tile-sized index windows of the
+  cartesian product inside one enumerate->score->select loop, so no
+  candidate tensor is ever materialized and caps reach
+  ``_PROD_LIM = 2**26``.
 """
 from __future__ import annotations
 
 import dataclasses
 import functools
 import itertools
-from typing import List, Tuple
+from typing import List
 
 import jax
 import jax.numpy as jnp
@@ -49,19 +45,8 @@ class ExplorerConfig:
     prob_threshold: float = 0.2
     max_candidates: int = 4096
     noise_samples: int = 1     # forward passes with independent noise
-    #: batched-route selection: "fused" streams candidate tiles through one
-    #: enumerate->score->select program (caps up to _PROD_LIM = 2**26);
-    #: "dense" keeps the reference route that materializes the (T, C_pad,
-    #: n_dims) tensor (caps up to _DENSE_LIM = 2**20).  Selections are
-    #: bit-identical either way (tests/test_fused_select.py).
-    batch_route: str = "fused"
     #: fused tile width — peak candidate memory is O(T * select_tile * d)
     select_tile: int = 1024
-
-
-# canonical definition lives beside the padding helpers it feeds;
-# re-exported here for the historical import path (selector, batcher)
-pow2_bucket = shard.pow2_bucket
 
 
 def row_seeds(seed, n: int) -> np.ndarray:
@@ -169,7 +154,7 @@ def enumerate_candidates(
 # ---------------------------------------------------------------------------
 # device-resident batched enumeration
 # ---------------------------------------------------------------------------
-#: largest max_candidates any batched route accepts (asserted at entry).
+#: largest max_candidates the batched route accepts (asserted at entry).
 #: Running cartesian-product values are clamped to _PROD_CLAMP during the
 #: on-device trim: strictly above any permitted cap, so a clamped value
 #: still compares `> cap` correctly.  The divide-form overflow guard in
@@ -178,16 +163,11 @@ def enumerate_candidates(
 #: topped out at 2**20).
 _PROD_LIM = 1 << 26
 _PROD_CLAMP = _PROD_LIM + 1
-#: largest cap the *dense* route will materialize as a (T, C_pad, n_dims)
-#: tensor; beyond it, only the streaming tiled route (core/fused_select)
-#: applies — it never materializes more than a tile.
-_DENSE_LIM = 1 << 20
 
 
 @functools.lru_cache(maxsize=None)
 def _enum_core(space: ConfigSpace):
-    """Traceable enumeration cores shared by the dense jitted wrappers
-    (``_batched_enum_fns``) and the streaming tiled route
+    """Traceable enumeration cores of the streaming tiled route
     (``core/fused_select``).
 
     ``masks_core``: probs (T, onehot_width) -> per-group keep masks +
@@ -195,8 +175,7 @@ def _enum_core(space: ConfigSpace):
     host ``enumerate_candidates`` (bit-for-bit: same probs in -> same sets
     out).  ``radix_core``: the kept sets -> the mixed-radix (table, stride)
     pair whose digit arithmetic unravels the cartesian product in
-    ``itertools.product`` order.  One definition feeds both consumers, so
-    the routes cannot drift.
+    ``itertools.product`` order.
     """
     gidx, mask, _ = padded_group_layout(space)
     n_groups, mx = mask.shape
@@ -251,65 +230,6 @@ def _enum_core(space: ConfigSpace):
         return table, stride
 
     return masks_core, radix_core
-
-
-@functools.lru_cache(maxsize=None)
-def _batched_enum_fns(space: ConfigSpace):
-    """Jitted (masks, unravel) pair for the dense on-device enumeration.
-
-    Thin jit wrappers over ``_enum_core``: ``unravel`` applies the mixed
-    -radix digit arithmetic to the full [0, c_pad) index range, yielding
-    the (T, c_pad, n_dims) padded candidate tensor — ``c_pad`` is static
-    so the jit cache holds one entry per power-of-two bucket.
-    """
-    masks_core, radix_core = _enum_core(space)
-    masks = jax.jit(masks_core)
-
-    @functools.partial(jax.jit, static_argnames="c_pad")
-    def unravel(keep, counts, total, c_pad):
-        table, stride = radix_core(keep, counts)
-        j = jnp.arange(c_pad, dtype=jnp.int32)
-        digit = (j[None, :, None] // stride[:, None, :]) % counts[:, None, :]
-        cand = jnp.take_along_axis(table, digit.transpose(0, 2, 1), axis=-1)
-        valid = j[None, :] < total[:, None]
-        return cand.transpose(0, 2, 1).astype(jnp.int32), valid
-
-    return masks, unravel
-
-
-def enumerate_candidates_batch(
-    space: ConfigSpace,
-    probs,
-    thresh: float,
-    max_candidates: int,
-) -> Tuple[jnp.ndarray, jnp.ndarray, np.ndarray]:
-    """Device twin of ``enumerate_candidates`` over a task batch.
-
-    probs: (T, onehot_width) array (host or device) ->
-      cand  (T, C_pad, n_dims) int32 device candidate indices,
-      valid (T, C_pad) bool device mask of real (non-padding) rows,
-      counts (T,) host int per-task candidate counts.
-
-    Row t's first counts[t] candidates equal ``enumerate_candidates`` on
-    probs[t] exactly.  C_pad is the next power of two >= max(counts),
-    bucketing recompiles to at most log2(max_candidates) cache entries.
-
-    This is the *reference* route: picking C_pad costs a mid-dispatch host
-    sync (the ``np.asarray(total)`` below — the GL112 bug class) and the
-    tensor caps out at ``_DENSE_LIM``.  The production batched path
-    (``core/fused_select``) streams the same enumeration in tiles with
-    neither limit.
-    """
-    assert space.max_group_size <= 1024 and 1 <= max_candidates <= _DENSE_LIM, \
-        "dense route needs max group size <= 1024 and cap <= 2**20 " \
-        "(use the fused tiled route for larger caps)"
-    masks, unravel = _batched_enum_fns(space)
-    keep, counts, total = masks(shard.put_sharded(probs), np.float32(thresh),
-                                np.int32(max_candidates))
-    counts_host = np.asarray(total)
-    c_pad = pow2_bucket(int(counts_host.max(initial=1)))
-    cand, valid = unravel(keep, counts, total, c_pad)
-    return cand, valid, counts_host
 
 
 def flatten_task_draws(net_enc, obj_enc, keys, n_samples: int, noise_fn):
@@ -444,15 +364,4 @@ class Explorer:
         probs = self.generator_probs(net_idx, lat_obj, pow_obj, seed)[0]
         return enumerate_candidates(
             self.model.space, probs, self.cfg.prob_threshold, self.cfg.max_candidates
-        )
-
-    def candidates_batch(self, net_idx: np.ndarray, lat_obj, pow_obj,
-                         seed: int = 0):
-        """Device-resident candidates for a task batch: G inference and the
-        cartesian-product enumeration both stay on device; see
-        `enumerate_candidates_batch` for the return contract."""
-        probs = self.generator_probs_device(net_idx, lat_obj, pow_obj, seed)
-        return enumerate_candidates_batch(
-            self.model.space, probs, self.cfg.prob_threshold,
-            self.cfg.max_candidates
         )

@@ -1,20 +1,17 @@
 """Streaming tiled select: enumerate -> score -> select as ONE program.
 
-The dense batched route (``enumerate_candidates_batch`` + ``select_batch``)
-materializes every candidate as a (T, C_pad, n_dims) tensor and walks it
-with a sequential length-C_pad Algorithm-2 scan: memory and latency both
-scale linearly with the candidate cap, a mid-dispatch host sync picks
-C_pad, and the cap tops out at the dense materialization bound
-(``explorer._DENSE_LIM`` = 2**20).
-
-This module fuses the three stages into one jitted program that loops
-over fixed-size candidate *tiles*:
+The batched Algorithm 2 route.  Materializing every candidate as a
+(T, C_pad, n_dims) tensor and walking it with a sequential length-C_pad
+scan would scale memory and latency linearly with the candidate cap and
+need a mid-dispatch host sync to pick C_pad.  This module instead fuses
+the three stages into one jitted program that loops over fixed-size
+candidate *tiles*:
 
 - each tile step decodes its tile-sized index window by *incremental*
   mixed-radix arithmetic: the in-tile offset digits are divmod-decoded
-  once per call (the dense route's ``unravel``, via the shared
-  ``explorer._enum_core`` radices) and every tile adds them to the
-  running tile-base digits with a carry-propagating compare/subtract —
+  once per call (via the ``explorer._enum_core`` radices) and every tile
+  adds them to the running tile-base digits with a carry-propagating
+  compare/subtract —
   zero integer divisions inside the loop (runtime-divisor divmod over
   (T, tile, n_dims) was ~half the route's wall time) and the full
   tensor is never materialized, so peak candidate memory is
@@ -61,11 +58,9 @@ program is static in everything but the task-bucket shape), and no
 wasted tiles when candidate sets are far below the cap.  Warm serve
 dispatch is one uninterrupted device program.
 
-Selections are bit-identical to the dense and host routes (pinned by
-``tests/test_fused_select.py``): identical float32 update-chain compares
-on identical oracle values, and winner metrics re-derived from the
-float64 host oracle through the same ``selections_from_winners`` tail as
-``select_batch``.
+Selections are bit-identical to the host route (pinned by
+``tests/test_fused_select.py``), and winner metrics are re-derived from
+the float64 host oracle through ``selections_from_winners``.
 """
 from __future__ import annotations
 
@@ -84,7 +79,7 @@ from repro.utils import trace
 
 #: default tile width — peak candidate memory is O(T * tile * n_dims)
 #: regardless of max_candidates, which is how caps up to _PROD_LIM = 2**26
-#: fit where the dense route stops at 2**20
+#: fit
 FUSED_TILE = 1024
 
 #: widest choice group decoded by a compare-select chain: the chain costs
@@ -105,8 +100,7 @@ def _gather_free(space: ConfigSpace) -> bool:
 
 def _fused_batch(model: DesignModel, space: ConfigSpace, tile: int):
     """Build the jitted enumerate->score->select program for one
-    (model, tile); cached on the model by `fused_select_batch` the way
-    selector caches ``_alg2_batch``."""
+    (model, tile); cached on the model by `fused_select_batch`."""
     masks_core, radix_core = _enum_core(space)
     rows = jnp.arange(tile, dtype=jnp.int32)
     n_dims = space.n_dims
@@ -114,7 +108,7 @@ def _fused_batch(model: DesignModel, space: ConfigSpace, tile: int):
 
     def radix_add(base, add, counts):
         # mixed-radix add of per-dim digit planes with the last dim least
-        # significant (itertools.product order, same radices as `unravel`);
+        # significant (itertools.product order, the `radix_core` strides);
         # both addends are digit-wise < counts so the ripple carry is at
         # most 1, and the dropped carry-out wraps mod prod(counts) exactly
         # like the divmod form does for indices past the raw product
@@ -202,8 +196,8 @@ def _fused_batch(model: DesignModel, space: ConfigSpace, tile: int):
             return space.values_from_indices_jax(cand.astype(jnp.int32))
 
         def decode_and_score(base_dig):
-            # the dense `unravel` digit arithmetic on a tile-sized window,
-            # via divmod-free incremental add of the tile-base digits
+            # the mixed-radix digits of a tile-sized index window, via
+            # divmod-free incremental add of the tile-base digits
             digits = radix_add([b[:, None] for b in base_dig], off_dig,
                                cnt_rows)
             lat, pw = model.evaluate_jax(net_vals, decode(digits))
@@ -294,8 +288,8 @@ def fused_select_batch(
     net_idx (T, n_net_dims), probs (T, onehot_width) (host or device, as
     produced by ``Explorer.generator_probs_device``), objectives (T,).
     Requires a jnp oracle (``model.has_jax_oracle``).  Task t's Selection
-    is bit-identical to the dense route's (``enumerate_candidates_batch``
-    + ``select_batch``) and to the host route's, at any tile size.
+    is bit-identical to the host route's (``enumerate_candidates`` +
+    ``select``), at any tile size.
 
     Under an active task mesh (``shard.set_task_mesh``) with T a multiple
     of the shard count, the inputs land task-sharded and the one fused

@@ -15,21 +15,19 @@ Models with a jnp oracle (``DesignModel.evaluate_jax``) run the whole
 thing — candidate scoring AND the update chain — as one jitted
 ``jax.lax.scan`` on device; candidate sets are padded to the next power of
 two so the jit cache stays small.  Models without a jnp port fall back to
-the original host loop.  ``select_batch`` vmaps the same scan over a task
-batch so all (T, C_pad) oracle evaluations and update chains resolve in a
-single dispatch.
+the original host loop.  Task batches take the fused tiled route
+(``core/fused_select``), whose host tail is ``selections_from_winners``.
 """
 from __future__ import annotations
 
 import dataclasses
-from typing import List, Optional, Tuple
+from typing import List, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import shard
-from repro.core.explorer import pow2_bucket
+from repro.core.shard import pow2_bucket
 from repro.design_models.base import DesignModel
 
 
@@ -66,40 +64,16 @@ def is_satisfied(lat: float, pw: float, lo: float, po: float,
 
 
 #: auto-route cutover: below this candidate count the host numpy loop is
-#: faster than dispatching the jitted scan (see `select` docstring).
-#: `benchmarks/bench_select_fused.py` reports the crossover it measures on
-#: the current host next to this configured value.
+#: faster than dispatching the jitted scan (see `select` docstring; a CPU
+#: choice).
 JAX_MIN_CANDIDATES = 512
-#: historical private name (pre-dating the `--select-route` override)
-_JAX_MIN_CANDIDATES = JAX_MIN_CANDIDATES
-
-#: process-wide `select` route override: None = auto (candidate-count
-#: crossover), False = force the host loop, True = force the device scan.
-#: Set via `set_select_route` (the `--select-route` launcher flag).
-_SELECT_ROUTE: Optional[bool] = None
-
-_ROUTE_NAMES = {"auto": None, "host": False, "device": True}
-
-
-def set_select_route(route: str) -> None:
-    """Override the per-task `select` auto-route: "auto" restores the
-    JAX_MIN_CANDIDATES crossover, "host" forces the float64 numpy loop,
-    "device" forces the jitted scan (models without a jnp oracle still
-    fall back to host).  Explicit ``use_jax=`` arguments always win."""
-    global _SELECT_ROUTE
-    if route not in _ROUTE_NAMES:
-        raise ValueError(f"select route must be one of {sorted(_ROUTE_NAMES)},"
-                         f" got {route!r}")
-    _SELECT_ROUTE = _ROUTE_NAMES[route]
 
 
 def _algorithm2_core(model: DesignModel):
     """Traceable single-task Algorithm 2: score + update chain in one scan.
 
-    valid marks real (non-padding) candidate rows.  Jitted directly for the
-    per-task route (`_algorithm2_scan`) and vmapped over a task batch for
-    `select_batch` — the update chain sees the same float32 values either
-    way, so batching never changes the winner.
+    valid marks real (non-padding) candidate rows.  Jitted for the
+    per-task device route (`_algorithm2_scan`).
     """
 
     def run(net_idx, cand_idx, valid, lo, po):
@@ -138,13 +112,6 @@ def _algorithm2_scan(model: DesignModel):
     """Jitted per-task Algorithm 2 (cached on the model); recompiles only
     per padded candidate count."""
     return jax.jit(_algorithm2_core(model))
-
-
-def _algorithm2_batch(model: DesignModel):
-    """Batched Algorithm 2: the single-task scan vmapped over tasks, so all
-    (T, C_pad) oracle evaluations and update chains run as ONE jitted
-    program (one dispatch for the whole task batch)."""
-    return jax.jit(jax.vmap(_algorithm2_core(model)))
 
 
 def _select_jax(
@@ -208,11 +175,8 @@ def select(
     if cand_idx.size == 0:
         return Selection(None, np.inf, np.inf, False, 0)
     if use_jax is None:
-        if _SELECT_ROUTE is None:
-            use_jax = (model.has_jax_oracle
-                       and cand_idx.shape[0] >= JAX_MIN_CANDIDATES)
-        else:       # --select-route override (see set_select_route)
-            use_jax = _SELECT_ROUTE and model.has_jax_oracle
+        use_jax = (model.has_jax_oracle
+                   and cand_idx.shape[0] >= JAX_MIN_CANDIDATES)
     if use_jax:
         return _select_jax(model, net_idx, cand_idx, lat_obj, pow_obj, noise_tol)
     net = np.repeat(np.atleast_2d(net_idx), cand_idx.shape[0], axis=0)
@@ -261,13 +225,12 @@ def selections_from_winners(
     pow_obj,
     noise_tol: float = NOISE_TOL,
 ) -> List[Selection]:
-    """Shared host tail of the batched device routes (`select_batch` and
-    the fused tiled route, ``core/fused_select``): given each task's
-    chosen candidate rank (-1 = none feasible) and winner config rows,
-    one batched float64 host-oracle call re-derives the reported metrics
-    — the device float32 only steered the update chains.  Rows with
-    ``chosen[t] < 0`` may hold arbitrary ``win_cfg`` values; they are
-    never evaluated."""
+    """Host tail of the fused tiled route (``core/fused_select``): given
+    each task's chosen candidate rank (-1 = none feasible) and winner
+    config rows, one batched float64 host-oracle call re-derives the
+    reported metrics — the device float32 only steered the update chains.
+    Rows with ``chosen[t] < 0`` may hold arbitrary ``win_cfg`` values;
+    they are never evaluated."""
     chosen = np.asarray(chosen)
     win = np.asarray(win_cfg)
     net_idx = np.asarray(net_idx, np.int32)
@@ -289,49 +252,3 @@ def selections_from_winners(
         out.append(Selection(win[t].copy(), l_opt, p_opt, satisfied, n))
     return out
 
-
-def select_batch(
-    model: DesignModel,
-    net_idx: np.ndarray,
-    cand_idx,
-    valid,
-    n_candidates: np.ndarray,
-    lat_obj: np.ndarray,
-    pow_obj: np.ndarray,
-    noise_tol: float = NOISE_TOL,
-) -> List[Selection]:
-    """Batched device Algorithm 2 over a padded candidate tensor.
-
-    net_idx (T, n_net_dims), cand_idx (T, C_pad, n_dims), valid (T, C_pad)
-    (as produced by ``enumerate_candidates_batch``), n_candidates (T,) real
-    per-task counts, objectives (T,).  Requires a jnp oracle
-    (``model.has_jax_oracle``).
-
-    All T update chains run as one jitted vmapped scan; like the per-task
-    device route, candidates are scored in float32 but the winners' reported
-    metrics and `satisfied` come from one batched float64 host-oracle call.
-    Task t's Selection equals ``select(model, net_idx[t],
-    cand_idx[t][:n_candidates[t]], ..., use_jax=True)``.
-
-    Under an active task mesh (``shard.set_task_mesh``) with T a multiple
-    of the shard count, all inputs land task-sharded and the vmapped scan
-    partitions across devices — same per-lane update chain, same winners.
-    """
-    run = model.__dict__.get("_alg2_batch")
-    if run is None:
-        run = model.__dict__["_alg2_batch"] = _algorithm2_batch(model)
-    net_idx = np.asarray(net_idx, np.int32)
-    n_tasks = net_idx.shape[0]
-    lo = np.asarray(lat_obj, np.float64).reshape(-1)
-    po = np.asarray(pow_obj, np.float64).reshape(-1)
-    _, _, chosen = run(
-        shard.put_sharded(net_idx), shard.put_sharded(cand_idx),
-        shard.put_sharded(valid),
-        shard.put_sharded(lo.astype(np.float32)),
-        shard.put_sharded(po.astype(np.float32)),
-    )
-    chosen = np.asarray(chosen)
-    cand_host = np.asarray(cand_idx)
-    win_cfg = cand_host[np.arange(n_tasks), np.maximum(chosen, 0)]
-    return selections_from_winners(model, net_idx, chosen, win_cfg,
-                                   n_candidates, lo, po, noise_tol)

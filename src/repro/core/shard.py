@@ -1,6 +1,6 @@
 """Task-axis sharding over a device mesh — the multi-chip DSE scale-out.
 
-Every batched DSE route (``GANDSE.explore_batch``/``select_batch``, the
+Every batched DSE route (``GANDSE.explore_batch``, the fused select, the
 MLP/SA/DRL device routes behind the same ``DSEMethod`` protocol) vmaps
 independent task lanes, so sharding the leading task axis over the mesh's
 batch axes ('pod', 'data') is pure throughput: the same jitted programs

@@ -36,7 +36,6 @@ import numpy as np
 from repro.core import gan as G
 from repro.core.dse_api import GANDSE, summarize
 from repro.core.explorer import ExplorerConfig
-from repro.core.selector import set_select_route
 from repro.dataset.generator import DSETask, generate_dataset, generate_tasks
 from repro.design_models.dnnweaver import DnnWeaverModel
 from repro.design_models.im2col import Im2colModel
@@ -67,18 +66,6 @@ def main(argv=None) -> int:
                     help="fraction of requests re-submitted verbatim "
                          "(exercises the cache/coalescing path)")
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--fused", choices=("auto", "on", "off"), default="auto",
-                    help="Pallas fused-MLP dispatch: auto = backend rule "
-                         "(TPU on, CPU/GPU off), on/off force it")
-    ap.add_argument("--batch-route", choices=("fused", "dense"),
-                    default="fused",
-                    help="batched selection: fused streaming tiles "
-                         "(default) or the dense reference route")
-    ap.add_argument("--select-route", choices=("auto", "host", "device"),
-                    default="auto",
-                    help="per-task select() fallback route: auto = the "
-                         "selector.JAX_MIN_CANDIDATES crossover, host/"
-                         "device force one (see set_select_route)")
     ap.add_argument("--concurrent", action="store_true",
                     help="serve through the threaded production front end "
                          "(futures + continuous batching) instead of the "
@@ -92,16 +79,13 @@ def main(argv=None) -> int:
                          "requests are shed before dispatch (0 = none)")
     args = ap.parse_args(argv)
     use_compile_cache()
-    use_fused = {"auto": None, "on": True, "off": False}[args.fused]
-    set_select_route(args.select_route)
 
     model = MODELS[args.model]()
     gan_cfg = G.GANConfig(n_net=model.net_space.n_dims).scaled(
         layers=args.layers, neurons=args.neurons, batch_size=64)
     engine = GANDSE(model, gan_cfg,
                     ExplorerConfig(prob_threshold=args.threshold,
-                                   max_candidates=args.max_candidates,
-                                   batch_route=args.batch_route))
+                                   max_candidates=args.max_candidates))
     if args.train_iters > 0:
         engine.train(args.data, args.train_iters, seed=args.seed)
     else:
@@ -111,8 +95,7 @@ def main(argv=None) -> int:
 
     srv = DSEServer(ServeConfig(max_batch=args.max_batch,
                                 cache_capacity=args.cache,
-                                max_queue=args.max_queue,
-                                use_fused=use_fused))
+                                max_queue=args.max_queue))
     srv.register(engine)
 
     n = args.requests

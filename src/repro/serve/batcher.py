@@ -36,7 +36,6 @@ from typing import Callable, Deque, Dict, List, Optional, Tuple
 import numpy as np
 
 from repro.core import shard
-from repro.core.explorer import pow2_bucket
 from repro.dataset.generator import DSETask
 from repro.serve.request import DSERequest
 
@@ -175,7 +174,7 @@ class MicroBatcher:
         k = self._shards()
         per_shard = -(-m // k)       # ceil(m / k)
         if self.pad_pow2:
-            per_shard = pow2_bucket(per_shard, floor=1)
+            per_shard = shard.pow2_bucket(per_shard, floor=1)
         target = per_shard * k
         if target > m:
             rows = np.concatenate([np.arange(m),

@@ -10,8 +10,8 @@ soak harness.
   the server's degraded-route fallback genuinely recovers;
 - **latency spikes**: seeded-random ``time.sleep`` stalls, exercising
   deadline shedding and queue backpressure without breaking correctness;
-- the wrapper is otherwise transparent (explore/train/attach/set_use_fused
-  pass through), so Selections are identical to the bare engine whenever a
+- the wrapper is otherwise transparent (explore/train/attach pass
+  through), so Selections are identical to the bare engine whenever a
   dispatch survives — the soak harness pins fault-run responses against
   standalone ``explore_tasks`` results.
 
@@ -80,12 +80,6 @@ class FaultyEngine:
     @property
     def gan_cfg(self):
         return getattr(self._inner, "gan_cfg", None)
-
-    def set_use_fused(self, use_fused):
-        setter = getattr(self._inner, "set_use_fused", None)
-        if setter is not None:
-            setter(use_fused)
-        return self
 
     def train(self, *a, **kw):
         return self._inner.train(*a, **kw)

@@ -99,10 +99,6 @@ class ServeConfig:
                                  # disables the fallback)
     degrade_probe_after: int = 4  # successful degraded dispatches between
                                   # device-route recovery probes
-    use_fused: Optional[bool] = None  # Pallas fused-MLP dispatch override
-                                      # pushed onto every registered engine
-                                      # (None = leave the engine's own
-                                      # setting / backend auto)
     n_shards: Optional[int] = None   # micro-batch shard multiple; None =
                                      # follow the active task mesh
                                      # (repro.core.shard), 1 = never shard
@@ -159,20 +155,13 @@ class DSEServer:
     # ---- registry ----------------------------------------------------------
     def register(self, engine: DSEMethod) -> DSEMethod:
         """Host ``engine`` for its design model (one engine per model name);
-        re-registering a name replaces the engine and drops its cache.
-        When ``ServeConfig.use_fused`` is set, the server pushes it onto
-        the engine (``set_use_fused``) so every hosted engine serves with
-        one consistent kernel route."""
+        re-registering a name replaces the engine and drops its cache."""
         name = engine.model.name
         if name in self.engines:
             # replacing an engine is a params change like any swap: retire
             # the cache entries and the generation in-flight batches carry
             self._params_gen[name] = self._params_gen.get(name, 0) + 1
             self.cache.invalidate_model(name)
-        if self.cfg.use_fused is not None:
-            setter = getattr(engine, "set_use_fused", None)
-            if setter is not None:
-                setter(self.cfg.use_fused)
         self.engines[name] = engine
         try:
             sig = inspect.signature(engine.explore_tasks)
@@ -608,14 +597,11 @@ class DSEServer:
                              if isinstance(getattr(e, "stats", None), dict)}
         s["inflight_attempts"] = dict(self._attempts)
         def engine_route(e) -> bool:
-            # the route this engine's dispatches actually take: the server
-            # -level flag when set, else the engine's own setting (backend
-            # conjunct included — "on" off-TPU still reports False)
-            flag = self.cfg.use_fused
-            if flag is None:
-                gc = getattr(e, "gan_cfg", None)
-                flag = gc.use_fused if gc is not None \
-                    else getattr(e, "use_fused", None)
+            # the route this engine's dispatches take, from its own setting
+            # (backend conjunct included — "on" off-TPU still reports False)
+            gc = getattr(e, "gan_cfg", None)
+            flag = gc.use_fused if gc is not None \
+                else getattr(e, "use_fused", None)
             return _dispatch.kernel_route_active(flag)
 
         s["kernels"] = {

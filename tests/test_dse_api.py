@@ -126,14 +126,13 @@ def test_baseline_routes_accept_per_row_seed_arrays():
     seeds = np.array([23, 5, 1_000_003], np.int64)
     for method in (RandomSearch(_MODEL, n_samples=32),
                    SimulatedAnnealing(_MODEL)):
-        # batched=False runs the host loop; force the same route on the
+        # batched=False runs the host loop; take the same route on the
         # single-task side (SA's bare explore would auto-route to device)
-        kw = {"use_jax": False} if hasattr(method, "_explore_host") else {}
+        explore = getattr(method, "_explore_host", method.explore)
         rows = method.explore_tasks(tasks, seed=seeds, batched=False)
         for i in range(3):
-            single = method.explore(tasks.net_idx[i], tasks.lat_obj[i],
-                                    tasks.pow_obj[i], seed=int(seeds[i]),
-                                    **kw)
+            single = explore(tasks.net_idx[i], tasks.lat_obj[i],
+                             tasks.pow_obj[i], int(seeds[i]))
             a, b = rows[i].selection, single.selection
             assert (a.cfg_idx is None) == (b.cfg_idx is None), method
             if a.cfg_idx is not None:

@@ -13,8 +13,8 @@ import pytest
 
 from repro.core import gan as G
 from repro.core.dse_api import GANDSE
-from repro.core.explorer import (ExplorerConfig, enumerate_candidates,
-                                 enumerate_candidates_batch)
+from repro.core.explorer import (_PROD_LIM, ExplorerConfig,
+                                 enumerate_candidates)
 from repro.dataset.generator import generate_tasks
 from repro.design_models.base import DesignModel
 from repro.design_models.dnnweaver import DnnWeaverModel
@@ -126,8 +126,12 @@ def test_explore_batch_falls_back_without_jax_oracle(models, tiny_gan_cfg,
 
 @pytest.mark.parametrize("name", sorted(MODELS))
 def test_enumeration_batch_matches_host(name, models):
-    """Device mixed-radix enumeration == host itertools.product, per task,
-    across thresholds and caps (including trim-forcing caps)."""
+    """The fused route's device enumeration masks (``_enum_core``) keep
+    exactly the host route's per-group choice sets, and count exactly its
+    candidates, per task, across thresholds and caps (including
+    trim-forcing caps)."""
+    from repro.core.explorer import _enum_core, _trimmed_employed
+
     space = models[name].space
     rng = np.random.default_rng(0)
     probs = np.stack([
@@ -135,28 +139,29 @@ def test_enumeration_batch_matches_host(name, models):
                         for d in space.dims]).astype(np.float32)
         for _ in range(6)       # T=6 everywhere: shapes hit the jit cache
     ])
+    masks = jax.jit(_enum_core(space)[0])
     for thresh, cap in [(0.2, 4096), (0.05, 64), (0.02, 1)]:
-        cand, valid, counts = enumerate_candidates_batch(space, probs,
-                                                         thresh, cap)
-        cand, valid = np.asarray(cand), np.asarray(valid)
+        keep, counts, total = jax.device_get(
+            masks(probs, np.float32(thresh), np.int32(cap)))
         for t in range(probs.shape[0]):
             host = enumerate_candidates(space, probs[t], thresh, cap)
-            assert counts[t] == host.shape[0] == valid[t].sum(), (thresh, cap)
-            np.testing.assert_array_equal(cand[t, :counts[t]], host)
+            assert total[t] == host.shape[0], (thresh, cap)
+            employed = _trimmed_employed(space, probs[t], thresh, cap)
+            for g, e in enumerate(employed):
+                np.testing.assert_array_equal(np.flatnonzero(keep[t, g]), e)
+                assert counts[t, g] == len(e)
 
 
-@pytest.mark.parametrize("n_groups,lim_name", [(8, "_DENSE_LIM"),
-                                               (10, "_PROD_LIM")])
-def test_enumeration_trim_at_cap_limit(n_groups, lim_name):
-    """cap == the route limit (dense 2**20, fused 2**26) must still trim on
+@pytest.mark.parametrize("n_groups,cap", [(8, 2 ** 20), (10, _PROD_LIM)])
+def test_enumeration_trim_at_cap_limit(n_groups, cap):
+    """A large cap (2**20) and the route limit (2**26) must still trim on
     device: the product clamp sits strictly above the cap (regression:
     clamping AT the cap made `> cap` unsatisfiable, disabling the trim and
     allocating the untrimmed cartesian product).  8 groups of 8 (2**24)
-    overflows the dense limit, 10 groups (2**30) the fused one; checked at
-    the mask level so the test never materializes the candidate tensor."""
-    import repro.core.explorer as explorer
+    overflow 2**20, 10 groups (2**30) the route limit; checked at the
+    mask level so the test never materializes a candidate set."""
     from repro.core.encoding import ConfigDim, ConfigSpace
-    from repro.core.explorer import _batched_enum_fns, _trimmed_employed
+    from repro.core.explorer import _enum_core, _trimmed_employed
 
     space = ConfigSpace(dims=tuple(
         ConfigDim(f"d{i}", tuple(float(j) for j in range(8)))
@@ -165,9 +170,8 @@ def test_enumeration_trim_at_cap_limit(n_groups, lim_name):
     probs = np.concatenate([rng.dirichlet(np.ones(8))
                             for _ in range(n_groups)]
                            ).astype(np.float32)[None]
-    cap = getattr(explorer, lim_name)
     assert 8 ** n_groups > cap                   # the trim must engage
-    masks_fn, _ = _batched_enum_fns(space)
+    masks_fn = jax.jit(_enum_core(space)[0])
     keep, counts, total = masks_fn(jnp.asarray(probs), jnp.float32(0.01),
                                    jnp.int32(cap))
     total = int(np.asarray(total)[0])
@@ -261,7 +265,7 @@ def test_warm_explore_batch_runs_no_eager_jax(models, tiny_gan_cfg,
 def test_oracle_broadcasts_task_by_candidate_grids(name, models):
     """(T, 1, n_net) x (T, C, n_cfg) -> (T, C): one grid call equals the
     stacked per-task calls, on both the jnp and numpy oracles.  (Eager jnp:
-    the jitted grid shape is compiled and exercised by select_batch in
+    the jitted grid shape is compiled and exercised by the fused select in
     test_explore_batch_matches_sequential; this pins the broadcast math
     without paying two more XLA compiles per model.)"""
     model = models[name]

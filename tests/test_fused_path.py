@@ -10,7 +10,7 @@ compiles — and pin:
 - LargeMLP baseline: same for its noise-averaged forward;
 - nn.mlp_apply: the non-ReLU-activation contract (raise on explicit
   use_fused=True, honored fallback on auto) and fused/unfused parity;
-- DSEServer: the ServeConfig.use_fused override reaches the engine.
+- DSEServer: the kernel report follows each engine's own setting.
 
 Caution for new tests: ``_cached_fwd`` memoizes jitted forwards on
 (space, gan_cfg, chained) — traces taken under force_interpret stay
@@ -215,24 +215,32 @@ def test_large_mlp_chained_route_parity(rng):
 
 
 # ---------------------------------------------------------------------------
-# serve-layer override
+# serve-layer kernel report
 # ---------------------------------------------------------------------------
-def test_serve_use_fused_override_reaches_engine(setup):
+def test_serve_kernel_report_follows_engine(setup):
+    """summary()["kernels"] reports the route each engine's own
+    ``gan_cfg.use_fused`` takes under the dispatch rule (the server sets
+    none), and the served answer equals a direct dispatch."""
+    from repro.dataset.generator import DSETask
     from repro.serve import DSEServer, ServeConfig
 
     model, cfg, ds, gp, dp, batch, rng = setup
-    engine = GANDSE(model, cfg)
-    engine.attach(ds, gp)
-    srv = DSEServer(ServeConfig(max_batch=4, use_fused=False))
-    srv.register(engine)
-    assert engine.gan_cfg.use_fused is False
-    s = srv.summary()
-    assert s["kernels"]["fused"][model.name] is False
-    assert "backend" in s["kernels"]
-    # the engine still serves correctly after the override re-attach:
-    # same Selection as a direct dispatch through the same batched route
-    from repro.dataset.generator import DSETask
-
+    for use_fused in (None, True, False):
+        engine = GANDSE(model, dataclasses.replace(cfg, use_fused=use_fused))
+        engine.attach(ds, gp)
+        srv = DSEServer(ServeConfig(max_batch=4))
+        srv.register(engine)
+        assert engine.gan_cfg.use_fused is use_fused
+        assert "backend" in srv.summary()["kernels"]
+        for hook in (False, True):
+            with D.force_interpret(hook):
+                got = srv.summary()["kernels"]["fused"][model.name]
+                assert got is D.kernel_route_active(use_fused)
+                # off the TPU only the hook reaches the kernels, and an
+                # explicit False stays on the jnp route even then
+                assert got is (hook and use_fused is not False)
+    # the engine pinned to the jnp route serves: same Selection as a
+    # direct dispatch through the same batched route
     rid = srv.submit(model.name, ds.net_idx[0], float(ds.latency[0] * 2),
                      float(ds.power[0] * 2), seed=3)
     srv.drain()
@@ -244,14 +252,3 @@ def test_serve_use_fused_override_reaches_engine(setup):
     np.testing.assert_array_equal(resp.result.selection.cfg_idx,
                                   want.selection.cfg_idx)
     assert resp.result.selection.satisfied == want.selection.satisfied
-
-
-def test_gandse_set_use_fused_rebuilds_explorer(setup):
-    model, cfg, ds, gp, dp, batch, rng = setup
-    engine = GANDSE(model, cfg)
-    engine.attach(ds, gp)
-    before = engine._explorer
-    engine.set_use_fused(False)
-    assert engine.gan_cfg.use_fused is False
-    assert engine._explorer is not before
-    assert engine._explorer.g_params is gp

@@ -1,8 +1,7 @@
 """Fused streaming tiled select (core/fused_select): parity pins.
 
 The contract under test: ``fused_select_batch`` returns Selections
-bit-identical to the dense route (``enumerate_candidates_batch`` +
-``select_batch``) and to the host route (``enumerate_candidates`` +
+bit-identical to the host route (``enumerate_candidates`` +
 ``select(use_jax=False)``), at ANY tile size — including the adversarial
 cases where a streaming implementation can silently diverge:
 
@@ -15,7 +14,7 @@ cases where a streaming implementation can silently diverge:
 - ragged per-task counts inside one batch.
 
 ``MixModel`` keeps every metric an exact small integer in float32, so
-the float32 device chains and the float64 host loop make identical
+the float32 device chain and the float64 host loop make identical
 accept decisions — the comparisons below are exact equality, never
 almost-equal.  Small moduli force many exact ties.
 
@@ -42,11 +41,10 @@ except ImportError:  # minimal CI image — seeded-random fallback
 
 from repro.core import shard
 from repro.core.encoding import ConfigDim, ConfigSpace
-from repro.core.explorer import (_enum_core, enumerate_candidates,
-                                 enumerate_candidates_batch)
+from repro.core.explorer import _enum_core, enumerate_candidates
 from repro.core.fused_select import (SELECT_CHAIN_MAX, _fused_batch,
                                      fused_select_batch)
-from repro.core.selector import select, select_batch
+from repro.core.selector import select
 from repro.design_models.base import DesignModel
 from repro.design_models.dnnweaver import DnnWeaverModel
 from repro.design_models.im2col import Im2colModel
@@ -129,20 +127,17 @@ def _widen(model, probs, rng, peak=0.9):
 
 
 def _routes(model, probs, thresh, cap, lo, po, tile):
-    """(fused, dense, host) Selections for the same inputs."""
+    """(fused, host) Selections for the same inputs."""
     t = probs.shape[0]
     net = np.zeros((t, 1), np.int32)
     fused = fused_select_batch(model, net, probs, thresh, cap, lo, po,
                                tile=tile)
-    cand, valid, counts = enumerate_candidates_batch(
-        model.space, probs, thresh, cap)
-    dense = select_batch(model, net, cand, valid, counts, lo, po)
     host = []
     for i in range(t):
         c = enumerate_candidates(model.space, probs[i], thresh, cap)
         host.append(select(model, net[i], c, float(lo[i]), float(po[i]),
                            use_jax=False))
-    return fused, dense, host
+    return fused, host
 
 
 def _assert_same(a, b):
@@ -231,23 +226,22 @@ def test_tiled_enumeration_property(sizes, seed, tile):
 
 
 # ---------------------------------------------------------------------------
-# Selection parity: fused == dense == host
+# Selection parity: fused == host
 # ---------------------------------------------------------------------------
 @DECODES
 @given(st.sampled_from(sorted(MODELS["chain"])), st.integers(0, 2 ** 31 - 1),
        st.sampled_from([0.02, 0.2, 0.5]), st.sampled_from([4, 8, 16]),
        st.floats(2.0, 50.0), st.floats(2.0, 40.0))
 @settings(max_examples=20, deadline=None)
-def test_fused_dense_host_parity(decode, name, seed, thresh, tile, lo0, po0):
+def test_fused_host_parity(decode, name, seed, thresh, tile, lo0, po0):
     model = MODELS[decode][name]
     probs = _probs(model, 4, seed)
     rng = np.random.default_rng(seed + 1)
     lo = np.float64(lo0) + rng.integers(0, 8, 4)    # integer-valued: exact
     po = np.float64(po0) + rng.integers(0, 8, 4)    # in f32 like the metrics
-    fused, dense, host = _routes(model, probs, thresh, 256, lo, po, tile)
+    fused, host = _routes(model, probs, thresh, 256, lo, po, tile)
     counts = {s.n_candidates for s in fused}
-    for f, d, h in zip(fused, dense, host):
-        _assert_same(f, d)
+    for f, h in zip(fused, host):
         _assert_same(f, h)
     assert len(counts) >= 1   # ragged batches occur across examples
 
@@ -263,9 +257,8 @@ def test_all_ties_first_candidate_wins_across_tiles(decode):
     lo = np.array([10.0, 0.5])      # satisfied and unsatisfied regimes
     po = np.array([10.0, 0.5])
     for tile in (3, 4, 64):
-        fused, dense, host = _routes(model, probs, 0.1, 256, lo, po, tile)
-        for f, d, h in zip(fused, dense, host):
-            _assert_same(f, d)
+        fused, host = _routes(model, probs, 0.1, 256, lo, po, tile)
+        for f, h in zip(fused, host):
             _assert_same(f, h)
             np.testing.assert_array_equal(f.cfg_idx[:3], [0, 0, 0])
 
@@ -281,30 +274,37 @@ def test_first_feasible_mid_tile_and_zero_feasible(decode):
     lo = np.array([20.0, 3.0, 40.0])
     po = np.array([20.0, 3.0, 40.0])
     for tile in (4, 8, 128):
-        fused, dense, host = _routes(holes, probs, 0.05, 256, lo, po, tile)
-        for f, d, h in zip(fused, dense, host):
-            _assert_same(f, d)
+        fused, host = _routes(holes, probs, 0.05, 256, lo, po, tile)
+        for f, h in zip(fused, host):
             _assert_same(f, h)
-    fused, dense, host = _routes(dead, probs, 0.05, 256, lo, po, 8)
-    for f, d, h in zip(fused, dense, host):
+    fused, host = _routes(dead, probs, 0.05, 256, lo, po, 8)
+    for f, h in zip(fused, host):
         assert f.cfg_idx is None and f.n_candidates == h.n_candidates
-        _assert_same(f, d)
         _assert_same(f, h)
 
 
 @DECODES
-def test_caps_beyond_dense_limit_accepted(decode):
-    """The fused route takes caps past the dense materialization bound
-    (2**20); the dense route still refuses them."""
+def test_fused_accepts_cap_2_26(decode):
+    """The fused route takes the largest cap, 2**26, and its compiled
+    program's temporaries stay O(T * tile * n_dims): the cap is an
+    argument, not a shape, so nothing near a (T, 2**26, n_dims)
+    candidate tensor is ever allocated."""
+    t, tile, cap = 2, 64, 1 << 26
     model = MODELS[decode]["plain"]
-    probs = _probs(model, 2, seed=9)
+    probs = _probs(model, t, seed=9)
     lo = po = np.array([20.0, 20.0])
-    net = np.zeros((2, 1), np.int32)
-    sels = fused_select_batch(model, net, probs, 0.02, 1 << 26, lo, po,
-                              tile=64)
+    net = np.zeros((t, 1), np.int32)
+    sels = fused_select_batch(model, net, probs, 0.02, cap, lo, po,
+                              tile=tile)
     assert all(s.cfg_idx is not None for s in sels)
-    with pytest.raises(AssertionError):
-        enumerate_candidates_batch(model.space, probs, 0.02, 1 << 26)
+    temp = _fused_batch(model, model.space, tile).lower(
+        jnp.asarray(probs), jnp.float32(0.02), jnp.int32(cap),
+        jnp.asarray(net), jnp.asarray(lo, jnp.float32),
+        jnp.asarray(po, jnp.float32),
+    ).compile().memory_analysis().temp_size_in_bytes
+    d = model.space.n_dims
+    assert temp <= 16 * t * tile * d * 4, temp
+    assert temp * 64 < t * cap * d * 4, temp
 
 
 def _accepting_tiles(model, probs, thresh, cap, lo, po, tile):

@@ -48,8 +48,9 @@ def test_fused_route_zero_recompiles_across_candidate_counts(engine,
     bucket: threshold and cap are traced arguments and the tile-loop trip
     count is ceil(max(total)/tile) computed on device, so warm dispatches
     with different candidate counts — including caps that span one tile
-    vs several — reuse ONE compiled program.  (The dense route cannot
-    pass this: its C_pad bucket is a static shape picked by a host sync.)
+    vs several — reuse ONE compiled program.  (A route that materialized
+    the candidate set could not pass this: its padded size would be a
+    static shape picked by a host sync.)
     """
     warm = generate_tasks(engine.model, 8, seed=11)
     engine.explore_batch(warm, seed=101)        # warm bucket 8 at cap 128

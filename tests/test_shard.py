@@ -283,26 +283,6 @@ def test_explore_batch_parity_sharded(model, mesh, tiny_gan_cfg,
 
 
 @multidevice
-def test_select_batch_parity_sharded(model, mesh, tiny_gan_cfg,
-                                     small_dataset):
-    from repro.core.selector import select_batch
-    eng = _attached(model, tiny_gan_cfg, small_dataset)
-    tasks = generate_tasks(model, 8, seed=4)
-    cand, valid, counts = eng._explorer.candidates_batch(
-        tasks.net_idx, tasks.lat_obj, tasks.pow_obj, seed=11)
-    base = select_batch(model, tasks.net_idx, cand, valid, counts,
-                        tasks.lat_obj, tasks.pow_obj)
-    with shard.task_mesh(mesh):
-        sharded = select_batch(model, tasks.net_idx, cand, valid, counts,
-                               tasks.lat_obj, tasks.pow_obj)
-    for i, (sa, sb) in enumerate(zip(base, sharded)):
-        if sa.cfg_idx is not None:
-            np.testing.assert_array_equal(sa.cfg_idx, sb.cfg_idx,
-                                          err_msg=f"select[{i}]")
-        assert sa.latency == sb.latency and sa.satisfied == sb.satisfied, i
-
-
-@multidevice
 def test_baseline_parity_sharded(model, mesh, small_dataset):
     from repro.baselines.drl import PolicyGradientDRL
     from repro.baselines.mlp import LargeMLP
