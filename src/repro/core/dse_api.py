@@ -54,11 +54,16 @@ def cache_key(model_name: str, net_idx: np.ndarray, lat_obj: float,
     noise key is PRNGKey(seed), independent of batch placement), so a
     cached result is indistinguishable from a recompute — until the
     engine's params change (`DSEServer.swap` invalidates the model's
-    entries).
+    entries).  The net part is one ``tolist()`` of the index array:
+    Python ints, equal and hash-equal whatever its integer dtype (any
+    other dtype goes through ``int``, as it always did).
     """
-    return (str(model_name),
-            tuple(int(v) for v in np.asarray(net_idx).reshape(-1)),
-            float(lat_obj), float(pow_obj), int(seed))
+    flat = np.asarray(net_idx).reshape(-1)
+    net = flat.tolist()
+    if flat.dtype.kind not in "iu":
+        net = map(int, net)
+    return (str(model_name), tuple(net), float(lat_obj), float(pow_obj),
+            int(seed))
 
 
 @dataclasses.dataclass

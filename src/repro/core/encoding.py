@@ -42,8 +42,9 @@ class ConfigSpace:
     def onehot_width(self) -> int:
         return sum(d.n for d in self.dims)
 
-    @property
+    @functools.cached_property
     def group_sizes(self) -> Tuple[int, ...]:
+        # cached: admission range-checks every served request against it
         return tuple(d.n for d in self.dims)
 
     @property

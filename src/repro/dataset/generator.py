@@ -8,7 +8,7 @@ normalized by the standard deviation (Tables 2-3).
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional, Sequence
+from typing import Optional
 
 import numpy as np
 
@@ -53,9 +53,9 @@ class Dataset:
 @dataclasses.dataclass
 class DSETask:
     """One DSE task batch: networks + the user's objectives `metric <= x`
-    (§5).  Row-wise slicing (`take`) and `concat` are what the serve
-    micro-batcher uses to coalesce independent in-flight requests into one
-    dispatchable batch and to pad it to a pow2 bucket."""
+    (§5).  The serve micro-batcher stacks one from its requests' rows
+    (`repro.serve.batcher`); row-wise slicing (`take`) pads a batch to a
+    shard multiple (`repro.core.shard`)."""
 
     net_idx: np.ndarray        # (T, n_net_dims)
     lat_obj: np.ndarray        # (T,) seconds
@@ -72,16 +72,6 @@ class DSETask:
         return DSETask(net_idx=np.atleast_2d(self.net_idx[idx]),
                        lat_obj=np.atleast_1d(self.lat_obj[idx]),
                        pow_obj=np.atleast_1d(self.pow_obj[idx]))
-
-    @staticmethod
-    def concat(tasks: "Sequence[DSETask]") -> "DSETask":
-        """Row-wise concatenation of task batches (coalescing)."""
-        assert len(tasks) > 0, "concat of zero task batches"
-        return DSETask(
-            net_idx=np.concatenate([np.atleast_2d(t.net_idx) for t in tasks]),
-            lat_obj=np.concatenate([np.atleast_1d(t.lat_obj) for t in tasks]),
-            pow_obj=np.concatenate([np.atleast_1d(t.pow_obj) for t in tasks]),
-        )
 
     @staticmethod
     def single(net_idx: np.ndarray, lat_obj: float, pow_obj: float) -> "DSETask":

@@ -169,17 +169,17 @@ class MicroBatcher:
                 self._queues.move_to_end(model_name)
 
         m = len(reqs)
-        tasks = DSETask.concat([r.as_task() for r in reqs])
-        seeds = np.array([r.seed for r in reqs], np.int64)
         k = self._shards()
         per_shard = -(-m // k)       # ceil(m / k)
         if self.pad_pow2:
             per_shard = shard.pow2_bucket(per_shard, floor=1)
-        target = per_shard * k
-        if target > m:
-            rows = np.concatenate([np.arange(m),
-                                   np.full(target - m, m - 1)])
-            tasks = tasks.take(rows)
-            seeds = seeds[rows]
+        # padding repeats the last real row; the batch is built from the
+        # requests' admission-time rows with one stack and three arrays
+        rows = reqs + [reqs[-1]] * (per_shard * k - m)
+        tasks = DSETask(
+            net_idx=np.stack([r.net_idx for r in rows]),
+            lat_obj=np.array([r.lat_obj for r in rows], np.float64),
+            pow_obj=np.array([r.pow_obj for r in rows], np.float64))
+        seeds = np.array([r.seed for r in rows], np.int64)
         return MicroBatch(model_name=model_name, requests=reqs,
                           tasks=tasks, seeds=seeds)

@@ -9,8 +9,8 @@ it.  `ServeFrontend` wraps one server with a two-stage pipeline:
   ``concurrent.futures.Future`` resolving to the request's `DSEResponse`
   — cache hits and admission rejections resolve immediately;
 - a **former** thread continuously sheds expired-deadline requests and
-  forms the next pow2-bucketed micro-batch (host-side work: concat,
-  padding) into a small bounded buffer;
+  forms the next pow2-bucketed micro-batch (host-side work: stacking the
+  requests' rows, padding) into a small bounded buffer;
 - a **dispatcher** thread executes buffered batches through the engine
   (``DSEServer.execute_batch``, the only stage that runs *outside* the
   front-end lock) — so host-side batching of micro-batch N+1 overlaps
@@ -203,7 +203,10 @@ class ServeFrontend:
             else:
                 self._futures[rid] = fut
                 self._meta[rid] = (model_name, t0)
-        self._work.set()
+        # one wake per burst: the former clears the event before it looks
+        # at the queues again, so a set event already covers this request
+        if not self._work.is_set():
+            self._work.set()
         fut.rid = rid  # type: ignore[attr-defined]
         return fut
 

@@ -56,14 +56,17 @@ class DSERequest:
     #: start of its queue wait; serving metadata, not task identity
     t_admit: float = dataclasses.field(default_factory=time.perf_counter,
                                        compare=False)
+    #: result-cache identity (see `repro.core.dse_api.cache_key`), built
+    #: once at construction: admission, coalescing, shedding and
+    #: publication all read it.  The deadline is serving metadata, not
+    #: task identity: two requests for the same work coalesce regardless
+    #: of their deadlines.
+    key: Tuple = dataclasses.field(init=False, repr=False, compare=False)
 
-    @property
-    def key(self) -> Tuple:
-        """Result-cache identity (see `repro.core.dse_api.cache_key`).
-        The deadline is serving metadata, not task identity: two requests
-        for the same work coalesce regardless of their deadlines."""
-        return cache_key(self.model_name, self.net_idx, self.lat_obj,
-                         self.pow_obj, self.seed)
+    def __post_init__(self) -> None:
+        object.__setattr__(self, "key", cache_key(
+            self.model_name, self.net_idx, self.lat_obj, self.pow_obj,
+            self.seed))
 
     def as_task(self) -> DSETask:
         """This request as a 1-row task batch."""

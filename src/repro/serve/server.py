@@ -50,10 +50,11 @@ from __future__ import annotations
 
 import dataclasses
 import inspect
+import operator
 import random
 import time
-from collections import OrderedDict
-from typing import Callable, Dict, List, Optional, Tuple
+from collections import OrderedDict, deque
+from typing import Callable, Deque, Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -122,7 +123,8 @@ class DSEServer:
         # bounded rid -> response map (oldest evicted past retention), so a
         # long-lived server under sustained traffic holds steady memory
         self._responses: "OrderedDict[int, DSEResponse]" = OrderedDict()
-        self._outbox: List[DSEResponse] = []
+        self._outbox: Deque[DSEResponse] = deque(
+            maxlen=max(self.cfg.response_retention, 1))
         self._attempts: Dict[int, int] = {}   # rid -> failed dispatch count
         self._consec_fail: Dict[str, int] = {}    # model -> consecutive fails
         self._backoff_until: Dict[str, float] = {}  # model -> monotonic time
@@ -203,21 +205,23 @@ class DSEServer:
         request is shed (REJECTED, with a retry-after hint) if it is still
         queued when the deadline passes."""
         assert model_name in self.engines, f"no engine for '{model_name}'"
-        # copy: asarray aliases an int64 caller buffer, and the request's
-        # cache/coalescing key is recomputed from net_idx at dispatch — a
-        # caller-side mutation must not desync it (or poison the cache)
+        # copy: asarray aliases an int64 caller buffer, and the batch is
+        # stacked from the request's net_idx at formation — a caller-side
+        # mutation must not desync it from the admission key (or poison
+        # the cache)
         net_idx = np.array(net_idx, np.int64, copy=True).reshape(-1)
         # reject at the door: a malformed request must never reach (and
         # poison) a batch — and a negative index would wrap silently in
-        # numpy, exploring (and caching!) the wrong network
-        net_space = self.engines[model_name].model.net_space
-        if net_idx.shape[0] != net_space.n_dims:
-            raise ValueError(f"net_idx has {net_idx.shape[0]} dims, "
-                             f"'{model_name}' expects {net_space.n_dims}")
-        sizes = np.asarray(net_space.group_sizes)
-        if np.any((net_idx < 0) | (net_idx >= sizes)):
-            raise ValueError(f"net_idx {net_idx.tolist()} out of range for "
-                             f"'{model_name}' (sizes {sizes.tolist()})")
+        # numpy, exploring (and caching!) the wrong network.  Checked on
+        # Python ints: no array is built per request.
+        sizes = self.engines[model_name].model.net_space.group_sizes
+        idx = net_idx.tolist()
+        if len(idx) != len(sizes):
+            raise ValueError(f"net_idx has {len(idx)} dims, "
+                             f"'{model_name}' expects {len(sizes)}")
+        if min(idx, default=0) < 0 or not all(map(operator.lt, idx, sizes)):
+            raise ValueError(f"net_idx {idx} out of range for "
+                             f"'{model_name}' (sizes {list(sizes)})")
         rid = self._next_rid
         self._next_rid += 1
         self.stats["submitted"] += 1
@@ -376,7 +380,8 @@ class DSEServer:
             if waits:
                 time.sleep(min(max(min(waits), 0.0),
                                self.cfg.retry_backoff_max) + 1e-4)
-        out, self._outbox = self._outbox, []
+        out = list(self._outbox)
+        self._outbox.clear()
         return out
 
     def response(self, rid: int) -> Optional[DSEResponse]:
@@ -565,11 +570,10 @@ class DSEServer:
         self._responses[resp.rid] = resp
         while len(self._responses) > max(self.cfg.response_retention, 1):
             self._responses.popitem(last=False)
+        # same bound for the drain outbox (its deque drops the oldest): a
+        # step()/response(rid) polling loop that never drains must not
+        # accumulate responses forever
         self._outbox.append(resp)
-        # same bound for the drain outbox: a step()/response(rid) polling
-        # loop that never drains must not accumulate responses forever
-        if len(self._outbox) > max(self.cfg.response_retention, 1):
-            del self._outbox[0]
         if self.on_response is not None:
             self.on_response(resp)
 
